@@ -18,6 +18,7 @@ import numpy as np
 
 sys.path.insert(0, "src")
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import (AffineProfile, KeyPositions, PROFILES, airtune,
                         expected_latency, IndexDesign, make_builders,
                         mean_read_volume)
@@ -341,7 +342,8 @@ def bench_tune():
     fmt = lambda v: f"{v:.0f}us" if isinstance(v, (int, float)) else "n/a"
     print(f"# tune-trend scoring: numpy={fmt(sb.get('numpy_us'))} "
           f"jnp={fmt(sb.get('jnp_us'))} "
-          f"pallas_interpret={fmt(sb.get('pallas_interpret_us'))}", flush=True)
+          f"pallas={fmt(sb.get('pallas_us'))} "
+          f"({sb.get('platform')}, {sb.get('pallas_mode')})", flush=True)
     if TUNE_JSON_PATH:
         import json
         with open(TUNE_JSON_PATH, "w") as f:
@@ -434,6 +436,7 @@ def _take_json_flag(argv: list, flag: str, default_path: str):
 def main() -> None:
     global SERVE_JSON_PATH, TUNE_JSON_PATH, BASELINE_JSON_PATH, \
         FLEET_JSON_PATH, CHAOS_JSON_PATH, P99_JSON_PATH
+    enable_compile_cache()
     argv = list(sys.argv[1:])
     # emit BENCH_*.json (perf trajectories)
     SERVE_JSON_PATH = _take_json_flag(argv, "--serve-json", "BENCH_serve.json")

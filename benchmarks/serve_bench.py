@@ -54,6 +54,7 @@ import numpy as np
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
                                 "..", "src"))
 
+from repro.compile_cache import enable_compile_cache
 from repro.api import Index, RetryPolicy, ServeSpec, TuneSpec, detect_drift
 from repro.core import (KeyPositions, PROFILES, airtune, expected_latency,
                         profile_to_dict, quantile_latency)
@@ -1295,6 +1296,7 @@ def run_serve_bench(n_keys: int = N_KEYS, n_queries: int = 4096) -> dict:
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--json", metavar="PATH", default=None,
                     help="also dump results as JSON (e.g. BENCH_serve.json)")

@@ -24,7 +24,8 @@ exists for, so the guided strategies ride the exhaustive pass's builds.
 The λ-grid keeps ``brute_force`` tractable; it certifies the guided
 strategies' costs on every run (``within_brute`` > 1.05 fails the run —
 the CI regression guard).  A scoring micro-benchmark also records the
-numpy / jnp / Pallas-interpret batched-scorer wall-clocks.
+numpy / jnp / Pallas batched-scorer wall-clocks, labelled with the
+platform that ran them and whether Pallas ran compiled or interpreted.
 
 Prints the repo's ``name,us_per_call,derived`` CSV; ``--json PATH`` also
 dumps ``BENCH_tune.json`` so the perf trajectory tracks tuner speed
@@ -43,6 +44,7 @@ import numpy as np
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
                                 "..", "src"))
 
+from repro.compile_cache import enable_compile_cache
 from repro.api import TuneSpec
 from repro.core import KeyPositions, PROFILES, batched_mean_read_costs
 from repro.core.registry import SEARCH_STRATEGIES
@@ -101,13 +103,20 @@ def _run_cell(strat: str, D, profile, builders, cache: LayerCache) -> dict:
 
 def _bench_scoring_backends(C: int = 32, S: int = 8192) -> dict:
     """Wall-clock of one batched (C, S) candidate-scoring call per
-    backend (fallback order Pallas → jnp → numpy; see
-    repro.kernels.candidate_score)."""
+    backend (see repro.kernels.candidate_score), labelled by what ran."""
+    import jax
+
+    from repro.core.storage import affine_coefficients
+    from repro.kernels import interpret_mode
+    from repro.kernels.candidate_score import affine_candidate_scores
+
     rng = np.random.default_rng(0)
     W = rng.uniform(16.0, 1e6, size=(C, S))
     weights = rng.uniform(0.5, 4.0, size=S)
     prof = PROFILES["azure_ssd"]
-    out = {"candidates": C, "sample": S}
+    out = {"candidates": C, "sample": S,
+           "platform": jax.default_backend(),
+           "pallas_mode": "interpret" if interpret_mode() else "compiled"}
 
     def _time(fn, reps=5):
         fn()                                     # warmup / jit compile
@@ -118,24 +127,16 @@ def _bench_scoring_backends(C: int = 32, S: int = 8192) -> dict:
 
     out["numpy_us"] = _time(
         lambda: batched_mean_read_costs(W, weights, prof))
-    # each device backend fails independently (e.g. jnp works but the
-    # Pallas interpret path raises on an older jax) — time them separately
-    for key, backend, reps in (("jnp_us", "jnp", 5),
-                               ("pallas_interpret_us", "pallas", 2)):
-        try:
-            from repro.core.storage import affine_coefficients
-            from repro.kernels.candidate_score import affine_candidate_scores
-            ell, inv_bw = affine_coefficients(prof)
-            out[key] = _time(lambda: affine_candidate_scores(
-                W, weights, ell, inv_bw, backend=backend), reps=reps)
-        except Exception as exc:                 # no jax / kernel failure
-            out[key] = None
-            out[f"{backend}_backend_error"] = repr(exc)
-    for k in ("numpy_us", "jnp_us", "pallas_interpret_us"):
-        v = out.get(k)
-        emit(f"tune_score_{k[:-3]}", v if v is not None else 0.0,
-             f"batched ({C},{S}) candidate scoring" if v is not None
-             else "backend unavailable")
+    ell, inv_bw = affine_coefficients(prof)
+    for backend, reps in (("jnp", 5), ("pallas", 2)):
+        out[f"{backend}_us"] = _time(lambda: affine_candidate_scores(
+            W, weights, ell, inv_bw, backend=backend), reps=reps)
+    for backend in ("numpy", "jnp", "pallas"):
+        ran = (f"{out['platform']}, {out['pallas_mode']}"
+               if backend == "pallas" else
+               "host" if backend == "numpy" else out["platform"])
+        emit(f"tune_score_{backend}", out[f"{backend}_us"],
+             f"batched ({C},{S}) candidate scoring on {ran}")
     return out
 
 
@@ -203,6 +204,7 @@ def run_tune_bench(n_keys: int = N_KEYS,
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--json", metavar="PATH", default=None,
                     help="also dump results as JSON (e.g. BENCH_tune.json)")

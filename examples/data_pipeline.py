@@ -15,6 +15,9 @@ import numpy as np
 sys.path.insert(0, "src")
 
 from repro.data.store import ShardedTokenStore, write_token_store
+from repro.compile_cache import enable_compile_cache
+
+enable_compile_cache()
 
 root = tempfile.mkdtemp(prefix="airindex-data-")
 rng = np.random.default_rng(0)

@@ -21,6 +21,9 @@ from repro.api import Index, TuneSpec
 from repro.core import KeyPositions, expected_latency, profile_local_storage
 from repro.core.baselines import build_fixed_btree, tune_pgm, tune_rmi
 from repro.data.datasets import sosd_like
+from repro.compile_cache import enable_compile_cache
+
+enable_compile_cache()
 
 workdir = tempfile.mkdtemp(prefix="airindex-")
 print(f"== profiling local storage ({workdir}) ==")

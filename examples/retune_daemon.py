@@ -43,6 +43,9 @@ from repro.core.serialize import read_meta_path
 from repro.data.datasets import sosd_like
 from repro.serve import FaultInjectingBackend, FileBackend
 from repro.serve.index_service import demo_serving_design
+from repro.compile_cache import enable_compile_cache
+
+enable_compile_cache()
 
 workdir = tempfile.mkdtemp(prefix="airindex-daemon-")
 gen_path = lambda g: os.path.join(workdir, f"index-gen{g}.air")  # noqa: E731

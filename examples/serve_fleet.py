@@ -32,6 +32,9 @@ from repro.api import ServeSpec, TuneSpec
 from repro.core import KeyPositions
 from repro.data.datasets import sosd_like
 from repro.fleet import Fleet, FleetSpec
+from repro.compile_cache import enable_compile_cache
+
+enable_compile_cache()
 
 workdir = tempfile.mkdtemp(prefix="airindex-fleet-")
 fleet_dir = os.path.join(workdir, "fleet")

@@ -35,6 +35,9 @@ from repro.api import Index, PROFILES, ServeSpec, TuneSpec
 from repro.core import KeyPositions, expected_latency
 from repro.serve.index_service import demo_serving_design
 from repro.data.datasets import sosd_like
+from repro.compile_cache import enable_compile_cache
+
+enable_compile_cache()
 
 workdir = tempfile.mkdtemp(prefix="airindex-serve-")
 path = os.path.join(workdir, "index.air")

@@ -22,6 +22,9 @@ from repro.configs import get_config
 from repro.models import api
 from repro.serve.kvcache import PagedKVCache
 from repro.serve.serve_step import make_decode_step
+from repro.compile_cache import enable_compile_cache
+
+enable_compile_cache()
 
 N_REQ = int(sys.argv[1]) if len(sys.argv) > 1 else 12
 STEPS = int(sys.argv[2]) if len(sys.argv) > 2 else 24

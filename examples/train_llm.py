@@ -27,6 +27,9 @@ from repro.train.fault_tolerance import (FTConfig, TrainingSupervisor,
                                          elastic_mesh_shape)
 from repro.train.optimizer import adamw_init
 from repro.train.train_step import TrainConfig, make_train_step
+from repro.compile_cache import enable_compile_cache
+
+enable_compile_cache()
 
 STEPS = int(sys.argv[1]) if len(sys.argv) > 1 else 40
 workdir = tempfile.mkdtemp(prefix="airindex-train-")

@@ -33,8 +33,8 @@ DEPRECATED_ENTRY_POINTS = {
 #: IndexService kwargs folded into ServeSpec; internal callers must pass
 #: spec=ServeSpec(...) instead (mirrors _fold_legacy_kwargs)
 LEGACY_KWARGS = ("cache_bytes", "cache_profile", "page_bytes",
-                 "resident_layers", "use_device", "interpret",
-                 "coalesce_gap", "persist_stats")
+                 "resident_layers", "use_device", "coalesce_gap",
+                 "persist_stats")
 
 #: callables whose keyword lists the legacy-kwarg check applies to
 _SERVICE_NAMES = {"IndexService"}

@@ -205,6 +205,12 @@ class RetryPolicy:
         return cls.from_dict(json.loads(s))
 
 
+#: fields older metas may carry that no longer configure anything: dropped
+#: on load so those files stay readable (``interpret`` is now decided by
+#: the platform at dispatch, see repro.kernels.interpret_mode)
+_RETIRED_SERVE_FIELDS = frozenset({"interpret"})
+
+
 @dataclasses.dataclass(frozen=True)
 class ServeSpec:
     """Everything the serving engine needs beyond (file, deployment tier).
@@ -229,9 +235,8 @@ class ServeSpec:
     backend:         resident-prefix descent backend — ``"numpy"`` is the
                      bit-exact float64 walk; ``"pallas"`` / ``"jnp"`` run
                      the fused f32 kernel (step layers exact, band layers
-                     δ-slack widened) with the Pallas → jnp → numpy
-                     fallback chain.
-    interpret:       run Pallas in interpret mode (CPU containers).
+                     δ-slack widened).  Pallas runs interpreted on the CPU
+                     and compiled elsewhere, decided at dispatch.
     coalesce_gap:    merge missing-page runs separated by ≤ this many
                      bytes (profitable when ``T(gap) − T(0) < ℓ``).
     persist_stats:   write a ServeStats snapshot next to the index on
@@ -258,7 +263,6 @@ class ServeSpec:
     page_bytes: int = 0
     resident_layers: int = 1
     backend: str = "numpy"
-    interpret: bool = True
     coalesce_gap: int = 0
     persist_stats: bool = False
     pipeline_depth: int = 0
@@ -314,6 +318,7 @@ class ServeSpec:
     @classmethod
     def from_dict(cls, d: dict) -> "ServeSpec":
         known = {f.name for f in dataclasses.fields(cls)}
+        d = {k: v for k, v in d.items() if k not in _RETIRED_SERVE_FIELDS}
         unknown = set(d) - known
         if unknown:
             raise ValueError(

@@ -8,6 +8,8 @@ keys; the generators accept any n).  gmm follows the paper exactly: a
 """
 from __future__ import annotations
 
+import zlib
+
 import numpy as np
 
 
@@ -17,7 +19,9 @@ def _dedup_sorted(keys: np.ndarray) -> np.ndarray:
 
 def sosd_like(name: str, n: int, seed: int = 0) -> np.ndarray:
     """→ sorted unique uint64 keys."""
-    rng = np.random.default_rng(seed + hash(name) % 2**16)
+    # crc32, not hash(): str hashes are salted per process, and the data a
+    # run serves must be regenerable from (name, n, seed) alone
+    rng = np.random.default_rng(seed + zlib.crc32(name.encode()) % 2**16)
     if name == "books":
         # heavy-tailed popularity counts accumulated (Amazon book sales)
         gaps = rng.zipf(1.31, int(n * 1.05)).astype(np.uint64)

@@ -15,7 +15,16 @@
                      with sequence-sharded KV via shard_map)
 
 Each kernel ships kernel.py (pl.pallas_call + BlockSpec), ops.py (jit'd
-public wrapper) and ref.py (pure-jnp oracle).  On this CPU container the
-kernels are validated with ``interpret=True``; on TPU the same code paths
-compile natively.
+public wrapper) and ref.py (pure-jnp oracle).  The serving and tuning
+dispatchers (fused_descent, candidate_score) run Pallas in interpret mode
+on the CPU and compiled on an accelerator, as :func:`interpret_mode`
+decides per call.
 """
+
+
+def interpret_mode() -> bool:
+    """Whether Pallas kernels run in interpret mode: on the CPU backend
+    only.  Decided by the platform at dispatch, never by a stored flag, so
+    an index written on a CPU host serves compiled on a TPU."""
+    import jax
+    return jax.default_backend() == "cpu"

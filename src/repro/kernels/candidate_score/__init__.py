@@ -1,8 +1,8 @@
 """Batched candidate scoring for the AirTune sweep engine.
 
 Evaluates the Eq. (9) ranking estimate ``Ê[T(Δ)]`` for a whole (C, S)
-matrix of candidate widths in one shot.  Backends, in fallback order
-Pallas → jnp → numpy (see :func:`ops.candidate_scores`):
+matrix of candidate widths in one shot.  Backends (see
+:func:`ops.candidate_scores`):
 
   * ``pallas`` — fused affine-profile weighted row-mean kernel
     (interpret mode on CPU, native on TPU),
@@ -12,8 +12,9 @@ Pallas → jnp → numpy (see :func:`ops.candidate_scores`):
 
 Device paths require an affine-representable tier
 (:func:`repro.core.storage.affine_coefficients`); anything else falls
-back to numpy.  They compute in float32 and are used for candidate
-*ranking* only — exact Eq. (6) costs always take the numpy path.
+back to numpy; a device backend's failure propagates.  They compute in
+float32 and are used for candidate *ranking* only — exact Eq. (6) costs
+always take the numpy path.
 """
 from .ops import affine_candidate_scores, candidate_scores
 from .ref import affine_scores_ref

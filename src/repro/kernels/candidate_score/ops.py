@@ -1,10 +1,10 @@
-"""Public dispatch for batched candidate scoring (Pallas → jnp → numpy).
+"""Public dispatch for batched candidate scoring (Pallas | jnp | numpy).
 
 ``candidate_scores`` is what the sweep engine calls: it folds the storage
-profile into affine coefficients when possible and walks the backend
-fallback chain; non-affine profiles and backend failures land on the
-bit-exact numpy evaluator.  Device backends compute in float32 — they
-rank candidates, they never produce the exact Eq. (6) costs.
+profile into affine coefficients when possible and runs the requested
+backend; non-affine profiles land on the bit-exact numpy evaluator, and a
+device backend's failure propagates.  Device backends compute in float32
+— they rank candidates, they never produce the exact Eq. (6) costs.
 """
 from __future__ import annotations
 
@@ -41,8 +41,7 @@ def _jitted_jnp(ell: float, inv_bw: float):
 
 
 def affine_candidate_scores(widths, weights, ell: float, inv_bw: float, *,
-                            backend: str = "numpy",
-                            interpret: bool = True) -> np.ndarray:
+                            backend: str = "numpy") -> np.ndarray:
     """Batched ``Ê[T(Δ)]`` under an affine tier, on the chosen backend."""
     if backend == "numpy":
         return ref.affine_scores_ref(widths, weights, ell, inv_bw)
@@ -54,33 +53,30 @@ def affine_candidate_scores(widths, weights, ell: float, inv_bw: float, *,
                                                      jnp.asarray(wt))
         return np.asarray(out, dtype=np.float64)
     if backend == "pallas":
+        from repro.kernels import interpret_mode
+
         from .kernel import BLOCK_C, LANE, affine_scores_pallas
         C = W.shape[0]
         Wp = _pad_to(_pad_to(W, LANE, 1), BLOCK_C, 0)
         wtp = _pad_to(wt, LANE, 0)          # zero-weight padding columns
         out = affine_scores_pallas(jnp.asarray(Wp), jnp.asarray(wtp),
                                    ell=float(ell), inv_bw=float(inv_bw),
-                                   interpret=interpret)
+                                   interpret=interpret_mode())
         return np.asarray(out, dtype=np.float64)[:C]
     raise ValueError(f"unknown backend {backend!r}")
 
 
-def candidate_scores(widths, weights, profile, *, backend: str = "pallas",
-                     interpret: bool = True) -> np.ndarray:
+def candidate_scores(widths, weights, profile, *,
+                     backend: str = "pallas") -> np.ndarray:
     """Score a (C, S) widths matrix under ``profile`` → (C,) float64.
 
-    Fallback order: requested device backend (Pallas, then jnp) → numpy.
-    Non-affine-representable profiles go straight to numpy — the device
-    closed form only exists for ``T(Δ) = ℓ + Δ/B`` tiers.
+    Runs the requested backend.  Non-affine-representable profiles go
+    straight to numpy — the device closed form only exists for
+    ``T(Δ) = ℓ + Δ/B`` tiers.
     """
     if backend != "numpy":
         co = affine_coefficients(profile)
         if co is not None:
-            chain = ("pallas", "jnp") if backend == "pallas" else (backend,)
-            for b in chain:
-                try:
-                    return affine_candidate_scores(
-                        widths, weights, *co, backend=b, interpret=interpret)
-                except Exception:   # missing jax / kernel failure: degrade
-                    continue
+            return affine_candidate_scores(widths, weights, *co,
+                                           backend=backend)
     return batched_mean_read_costs(widths, weights, profile)

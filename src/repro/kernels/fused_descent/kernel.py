@@ -11,7 +11,7 @@ fused grid instead of L chained dispatches.
 Grid ``(n_q_blocks, L)`` — the layer dimension is innermost, and TPU grids
 are executed sequentially per core, so the Pallas pipeline double-buffers
 the per-layer parameter planes (the ``flash_attention`` idiom: while layer
-``l`` computes, layer ``l+1``'s (1, P) plane tiles are already streaming
+``l`` computes, layer ``l+1``'s (1, P) plane blocks are already streaming
 into the second VMEM buffer).  The query block is cast to f32 once into a
 VMEM scratch that persists across the layer iterations of one query cell.
 
@@ -24,14 +24,19 @@ which keeps the kernel free of data-dependent control flow.
 Plane layout (packed by ``ops.pack_prefix``, one row per layer, padded to a
 common LANE-multiple width P):
 
-  kinds            (L,)    int32   0 step / 1 band
-  keys             (L, P)  int32   partition keys (KEY_PAD beyond the layer)
-  pos_lo, pos_hi   (L, P)  int32   step piece ranges      (zeros on band rows)
-  x1, y1, m, delta (L, P)  f32     band line params, δ pre-widened by the
-                                   f32 slack                (zeros on step rows)
+  kinds            (L,)       int32  0 step / 1 band; whole vector in SMEM
+  keys             (L, 1, P)  int32  partition keys (KEY_PAD beyond the layer)
+  pos_lo, pos_hi   (L, 1, P)  int32  step piece ranges    (zeros on band rows)
+  x1, y1, m, delta (L, 1, P)  f32    band line params, δ pre-widened by the
+                                     f32 slack              (zeros on step rows)
 
-Outputs are (L, Q) int32 ``lo``/``hi``: row ``l`` is layer ``l``'s window
-for every query; row ``L-1`` feeds the on-disk walk.
+Queries arrive as one (1, Q) row; outputs are (L, 1, Q) int32 ``lo``/``hi``:
+row ``l`` is layer ``l``'s window for every query; row ``L-1`` feeds the
+on-disk walk.  The unit middle axis is what the TPU compiler needs: it
+tiles the last two dims of every block by (8, 128) unless a dim spans the
+whole array, so a layer's block is ``(None, 1, P)`` (layer axis squeezed,
+a full-extent unit row, lane-aligned P) and a query block is
+``(1, BLOCK_Q)``; a plain (L, P) plane with a (1, P) block is refused.
 """
 from __future__ import annotations
 
@@ -64,11 +69,11 @@ def _gather(values, idx, P):
 def _fused_kernel(kind_ref, q_ref, keys_ref, pos_lo_ref, pos_hi_ref,
                   x1_ref, y1_ref, m_ref, d_ref, lo_ref, hi_ref, qf_ref):
     l = pl.program_id(1)
-    q = q_ref[...]                              # (Bq,) int32
+    q = q_ref[0]                                # (Bq,) int32
 
     @pl.when(l == 0)
     def _stage_queries():                       # f32 cast once per q-cell;
-        qf_ref[...] = q.astype(jnp.float32)     # reused by every band layer
+        qf_ref[...] = q_ref[...].astype(jnp.float32)  # reused by band layers
 
     keys = keys_ref[0]                          # (P,) this layer's plane
     P = keys.shape[0]
@@ -83,34 +88,35 @@ def _fused_kernel(kind_ref, q_ref, keys_ref, pos_lo_ref, pos_hi_ref,
     y1 = _gather(y1_ref[0], i, P)
     m = _gather(m_ref[0], i, P)
     d = _gather(d_ref[0], i, P)
-    mid = y1 + m * (qf_ref[...] - x1)
+    mid = y1 + m * (qf_ref[0] - x1)
     blo = jnp.floor(mid - d).astype(jnp.int32)
     bhi = jnp.maximum(jnp.ceil(mid + d).astype(jnp.int32), blo + 1)
 
-    is_band = kind_ref[0] == 1
+    is_band = kind_ref[l] == 1
     lo_ref[0] = jnp.where(is_band, blo, slo)
     hi_ref[0] = jnp.where(is_band, bhi, shi)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def fused_descent_pallas(queries, kinds, keys, pos_lo, pos_hi, x1, y1, m,
-                         delta, *, interpret=True):
-    """queries (Q,) int32, Q multiple of BLOCK_Q; planes (L, P), P multiple
-    of LANE → (lo, hi) int32 of shape (L, Q)."""
-    Q = queries.shape[0]
-    L, P = keys.shape
+                         delta, *, interpret=False):
+    """queries (1, Q) int32, Q multiple of BLOCK_Q; kinds (L,) int32;
+    planes (L, 1, P), P multiple of LANE → (lo, hi) int32 of shape
+    (L, 1, Q)."""
+    Q = queries.shape[1]
+    L, _, P = keys.shape
     assert Q % BLOCK_Q == 0 and P % LANE == 0 and L >= 1
     grid = (Q // BLOCK_Q, L)      # layer innermost: planes double-buffer
-    qspec = pl.BlockSpec((BLOCK_Q,), lambda iq, l: (iq,))
-    kspec = pl.BlockSpec((1,), lambda iq, l: (l,))
-    pspec = pl.BlockSpec((1, P), lambda iq, l: (l, 0))
-    ospec = pl.BlockSpec((1, BLOCK_Q), lambda iq, l: (l, iq))
+    kspec = pl.BlockSpec(memory_space=pltpu.SMEM)   # whole (L,) vector
+    qspec = pl.BlockSpec((1, BLOCK_Q), lambda iq, l: (0, iq))
+    pspec = pl.BlockSpec((None, 1, P), lambda iq, l: (l, 0, 0))
+    ospec = pl.BlockSpec((None, 1, BLOCK_Q), lambda iq, l: (l, 0, iq))
     return pl.pallas_call(
         _fused_kernel,
         grid=grid,
         in_specs=[kspec, qspec] + [pspec] * 7,
         out_specs=[ospec, ospec],
-        out_shape=[jax.ShapeDtypeStruct((L, Q), jnp.int32)] * 2,
-        scratch_shapes=[pltpu.VMEM((BLOCK_Q,), jnp.float32)],  # staged q f32
+        out_shape=[jax.ShapeDtypeStruct((L, 1, Q), jnp.int32)] * 2,
+        scratch_shapes=[pltpu.VMEM((1, BLOCK_Q), jnp.float32)],  # staged q
         interpret=interpret,
     )(kinds, queries, keys, pos_lo, pos_hi, x1, y1, m, delta)

@@ -1,4 +1,4 @@
-"""Public dispatch for the fused multi-layer descent (Pallas → jnp → numpy).
+"""Public dispatch for the fused multi-layer descent (Pallas | jnp | numpy).
 
 ``fused_descent`` is what the serving engine calls per batch: one op walks
 the queries through the whole resident layer prefix and returns the (L, Q)
@@ -7,9 +7,13 @@ per-layer windows.  The numpy backend *is*
 path for every registered family.  The device backends compute in
 int32/float32: step rows stay exact, band rows are widened by the δ slack
 of :mod:`repro.kernels.index_lookup` (ranges remain valid under Eq. 1 but
-may be strictly wider), mirroring the engine's previous ``use_device``
-semantics.  Backend failures degrade down the chain like
-``candidate_score`` — a container without jax always lands on numpy.
+may be strictly wider).
+
+A device backend serves exactly as requested or raises: a kernel failure
+is never swallowed.  Only batches the int32 planes cannot represent go to
+numpy, and :func:`fused_descent_with_backend` names why (``"width"``,
+``"key_range"``, ``"query_range"``).  Pallas runs in interpret mode on the
+CPU and compiled everywhere else (:func:`repro.kernels.interpret_mode`).
 """
 from __future__ import annotations
 
@@ -39,60 +43,70 @@ def _pad_up(n: int, mult: int) -> int:
     return n + (-n) % mult
 
 
+def prefix_gate(layers) -> str | None:
+    """Why a non-empty top-down prefix cannot be packed into the fused
+    kernel's int32 planes: ``"width"`` when a layer is wider than the VMEM
+    bound, ``"key_range"`` when a key or position reaches 2**31 - 1, else
+    None (packable)."""
+    widths = [len(lay["keys"] if lay["kind"] == "step" else lay["x1"])
+              for lay in layers]
+    if _pad_up(max(widths), LANE) > MAX_VMEM_ENTRIES:
+        return "width"
+    for lay in layers:
+        cols = (("keys", "pos_hi") if lay["kind"] == "step" else ("x1",))
+        if any(int(lay[c].max(initial=0)) >= _I32_LIM for c in cols):
+            return "key_range"
+    return None
+
+
 def pack_prefix(layers) -> dict | None:
     """Pack a top-down resident prefix (parsed layer dicts, the
     :class:`repro.serve.IndexService` representation) into the fused
-    kernel's (L, P) planes.
+    kernel's planes: ``kinds`` (L,) and seven (L, 1, P) planes, P the
+    common LANE-padded width.
 
-    Returns None when the prefix is empty, any layer overflows int32, or
-    the common padded width exceeds the VMEM bound — callers then serve on
-    the numpy path, exactly like the per-layer device gating did.
-    Pure numpy: packing works without jax; only dispatch needs it.
+    Returns None when the prefix is empty or :func:`prefix_gate` declines
+    it — callers then serve on the numpy path.  Pure numpy: packing works
+    without jax; only dispatch needs it.
     """
     L = len(layers)
-    if L == 0:
+    if L == 0 or prefix_gate(layers) is not None:
         return None
     widths = [len(lay["keys"] if lay["kind"] == "step" else lay["x1"])
               for lay in layers]
     P = _pad_up(max(widths), LANE)
-    if P > MAX_VMEM_ENTRIES:
-        return None
     kinds = np.zeros(L, dtype=np.int32)
-    keys = np.full((L, P), KEY_PAD, dtype=np.int32)
-    pos_lo = np.zeros((L, P), dtype=np.int32)
-    pos_hi = np.zeros((L, P), dtype=np.int32)
-    x1 = np.zeros((L, P), dtype=np.float32)
-    y1 = np.zeros((L, P), dtype=np.float32)
-    m = np.zeros((L, P), dtype=np.float32)
-    delta = np.zeros((L, P), dtype=np.float32)
+    keys = np.full((L, 1, P), KEY_PAD, dtype=np.int32)
+    pos_lo = np.zeros((L, 1, P), dtype=np.int32)
+    pos_hi = np.zeros((L, 1, P), dtype=np.int32)
+    x1 = np.zeros((L, 1, P), dtype=np.float32)
+    y1 = np.zeros((L, 1, P), dtype=np.float32)
+    m = np.zeros((L, 1, P), dtype=np.float32)
+    delta = np.zeros((L, 1, P), dtype=np.float32)
     for l, lay in enumerate(layers):
         n = widths[l]
         if lay["kind"] == "step":
-            if (int(lay["keys"].max(initial=0)) >= _I32_LIM
-                    or int(lay["pos_hi"].max(initial=0)) >= _I32_LIM):
-                return None
-            keys[l, :n] = lay["keys"]
-            pos_lo[l, :n] = lay["pos_lo"]
-            pos_hi[l, :n] = lay["pos_hi"]
+            keys[l, 0, :n] = lay["keys"]
+            pos_lo[l, 0, :n] = lay["pos_lo"]
+            pos_hi[l, 0, :n] = lay["pos_hi"]
         else:
-            if int(lay["x1"].max(initial=0)) >= _I32_LIM:
-                return None
             kinds[l] = 1
-            keys[l, :n] = lay["x1"]
-            x1[l, :n] = lay["x1"].astype(np.float32)
-            y1[l, :n] = np.asarray(lay["y1"], dtype=np.float32)
-            m[l, :n] = np.asarray(lay["m"], dtype=np.float32)
-            delta[l, :n] = (np.asarray(lay["delta"], dtype=np.float64)
-                            + band_f32_slack(lay["y1"], lay["m"],
-                                             lay["x1"])).astype(np.float32)
+            keys[l, 0, :n] = lay["x1"]
+            x1[l, 0, :n] = lay["x1"].astype(np.float32)
+            y1[l, 0, :n] = np.asarray(lay["y1"], dtype=np.float32)
+            m[l, 0, :n] = np.asarray(lay["m"], dtype=np.float32)
+            delta[l, 0, :n] = (np.asarray(lay["delta"], dtype=np.float64)
+                               + band_f32_slack(lay["y1"], lay["m"],
+                                                lay["x1"])).astype(np.float32)
     return {"kinds": kinds, "keys": keys, "pos_lo": pos_lo, "pos_hi": pos_hi,
             "x1": x1, "y1": y1, "m": m, "delta": delta}
 
 
-def _device_descent(planes: dict, q: np.ndarray, backend: str,
-                    interpret: bool):
+def _device_descent(planes: dict, q: np.ndarray, backend: str):
     """One device dispatch over packed planes → float64 (L, Q) rows."""
     import jax.numpy as jnp
+
+    from repro.kernels import interpret_mode
 
     from . import kernel as K
 
@@ -107,8 +121,9 @@ def _device_descent(planes: dict, q: np.ndarray, backend: str,
         jplanes = [jnp.asarray(planes[k]) for k in
                    ("kinds", "keys", "pos_lo", "pos_hi", "x1", "y1", "m",
                     "delta")]
-        lo, hi = K.fused_descent_pallas(qi, *jplanes, interpret=interpret)
-        lo, hi = lo[:, :nq], hi[:, :nq]
+        lo, hi = K.fused_descent_pallas(qi[None, :], *jplanes,
+                                        interpret=interpret_mode())
+        lo, hi = lo[:, 0, :nq], hi[:, 0, :nq]
     else:
         raise ValueError(f"unknown device backend {backend!r}")
     return (np.asarray(lo, dtype=np.float64),
@@ -116,40 +131,41 @@ def _device_descent(planes: dict, q: np.ndarray, backend: str,
 
 
 def fused_descent_with_backend(layers, queries, *, backend: str = "pallas",
-                               interpret: bool = True, packed=None):
-    """Like :func:`fused_descent` but also reports the backend that
-    actually served: ``(lo, hi, backend_used)`` — the engine attributes
-    ``device_batches`` from it."""
+                               packed=None):
+    """Like :func:`fused_descent` but also reports who served and why:
+    ``(lo, hi, backend_used, numpy_reason)``.  ``numpy_reason`` is None
+    unless a device backend was requested and numpy served the batch:
+    then it is ``"width"`` or ``"key_range"`` (:func:`prefix_gate`) or
+    ``"query_range"`` (a query reaches 2**31 - 1).  An empty prefix or
+    batch has nothing to descend and serves on numpy with no reason.  A
+    device backend's own failure propagates."""
     q = np.atleast_1d(np.asarray(queries, dtype=np.uint64))
-    if backend != "numpy":
+    reason = None
+    if backend != "numpy" and layers and len(q):
         if packed is None:
-            packed = pack_prefix(layers)
-        if (packed is not None and len(q)
-                and int(q.max(initial=0)) < _I32_LIM):
-            chain = ("pallas", "jnp") if backend == "pallas" else (backend,)
-            for b in chain:
-                try:
-                    lo, hi = _device_descent(packed, q, b, interpret)
-                except Exception:   # missing jax / kernel failure: degrade
-                    continue
-                return lo, hi, b
+            reason = prefix_gate(layers)
+            packed = pack_prefix(layers) if reason is None else None
+        if reason is None and int(q.max()) >= _I32_LIM:
+            reason = "query_range"
+        if reason is None:
+            lo, hi = _device_descent(packed, q, backend)
+            return lo, hi, backend, None
     lo, hi = ref.fused_descent_ref(layers, q)
-    return lo, hi, "numpy"
+    return lo, hi, "numpy", reason
 
 
-def fused_descent(layers, queries, *, backend: str = "pallas",
-                  interpret: bool = True, packed=None):
+def fused_descent(layers, queries, *, backend: str = "pallas", packed=None):
     """Walk ``queries`` through a resident prefix in one fused dispatch →
     ``(lo, hi)`` float64 arrays of shape (L, Q), row ``l`` = layer ``l``'s
     window per query (top-down; row L−1 feeds the disk walk).
 
-    Fallback order: requested device backend (Pallas, then jnp) → numpy.
-    ``backend="numpy"`` (and every chain exhaustion) is bit-identical to
-    the per-layer :func:`repro.core.descent.descend_layers` walk; device
-    backends keep step rows exact and widen band rows by the f32 δ slack.
-    ``packed`` lets long-lived callers reuse one :func:`pack_prefix`
-    result across batches.
+    ``backend="numpy"`` (and every batch the device planes cannot
+    represent) is bit-identical to the per-layer
+    :func:`repro.core.descent.descend_layers` walk; device backends keep
+    step rows exact and widen band rows by the f32 δ slack.  ``packed``
+    lets long-lived callers reuse one :func:`pack_prefix` result across
+    batches.
     """
-    lo, hi, _ = fused_descent_with_backend(layers, queries, backend=backend,
-                                           interpret=interpret, packed=packed)
+    lo, hi, _, _ = fused_descent_with_backend(layers, queries,
+                                              backend=backend, packed=packed)
     return lo, hi

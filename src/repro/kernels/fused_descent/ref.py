@@ -9,9 +9,9 @@ Two oracles with different contracts:
   * :func:`fused_descent_jnp` — pure-jnp f32 oracle over the *packed*
     planes, mirroring the kernel's semantics (int32 keys, f32 band math on
     the slack-widened δ, per-layer ``hi ≥ lo+1`` on band rows).  This is
-    both the kernel's test oracle and the middle link of the
-    Pallas → jnp → numpy fallback chain; it may differ from the kernel by
-    a few ULP of the f32 band midpoint (FMA contraction), never more.
+    both the kernel's test oracle and the ``backend="jnp"`` path; it may
+    differ from the kernel by a few ULP of the f32 band midpoint (FMA
+    contraction), never more.
 """
 from __future__ import annotations
 
@@ -36,22 +36,24 @@ def fused_descent_jnp(planes: dict, queries):
     q = jnp.asarray(queries, jnp.int32)
     qf = q.astype(jnp.float32)
     kinds = np.asarray(planes["kinds"])
-    keys = jnp.asarray(planes["keys"])
+    rows = {k: jnp.asarray(planes[k])[:, 0] for k in
+            ("keys", "pos_lo", "pos_hi", "x1", "y1", "m", "delta")}
+    keys = rows["keys"]
     los, his = [], []
     for l in range(keys.shape[0]):
         # rank − 1 == searchsorted-right − 1: the covering partition
         i = jnp.clip(jnp.searchsorted(keys[l], q, side="right") - 1, 0, None)
         if kinds[l] == 1:
-            x1 = jnp.asarray(planes["x1"])[l][i]
-            y1 = jnp.asarray(planes["y1"])[l][i]
-            m = jnp.asarray(planes["m"])[l][i]
-            d = jnp.asarray(planes["delta"])[l][i]
+            x1 = rows["x1"][l][i]
+            y1 = rows["y1"][l][i]
+            m = rows["m"][l][i]
+            d = rows["delta"][l][i]
             mid = y1 + m * (qf - x1)
             lo = jnp.floor(mid - d).astype(jnp.int32)
             hi = jnp.maximum(jnp.ceil(mid + d).astype(jnp.int32), lo + 1)
         else:
-            lo = jnp.asarray(planes["pos_lo"])[l][i]
-            hi = jnp.asarray(planes["pos_hi"])[l][i]
+            lo = rows["pos_lo"][l][i]
+            hi = rows["pos_hi"][l][i]
         los.append(lo)
         his.append(hi)
     return jnp.stack(los), jnp.stack(his)

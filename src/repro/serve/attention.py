@@ -46,9 +46,5 @@ def flash_decode_sharded(mesh: Mesh, axis: str = "model"):
 
     in_specs = (P(), P(None, None, axis, None), P(None, None, axis, None),
                 P())
-    if hasattr(jax, "shard_map"):            # jax >= 0.6
-        return jax.shard_map(local, mesh=mesh, in_specs=in_specs,
-                             out_specs=P(), check_vma=False)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(local, mesh=mesh, in_specs=in_specs, out_specs=P(),
-                     check_rep=False)
+    return jax.shard_map(local, mesh=mesh, in_specs=in_specs,
+                         out_specs=P(), check_vma=False)

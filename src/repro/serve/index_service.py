@@ -19,7 +19,8 @@ serves *batches* against one serialized index through three mechanisms:
      Alg. 1) and descended in ONE fused dispatch per batch
      (:mod:`repro.kernels.fused_descent`): the numpy backend is the
      bit-exact float64 walk; ``backend="pallas"``/``"jnp"`` run the fused
-     f32 kernel with the Pallas → jnp → numpy fallback chain;
+     f32 kernel, and only batches its int32 planes cannot represent go to
+     numpy — counted per reason in :class:`ServeStats`;
   4. **two-stage pipeline** — :meth:`IndexService.lookup_batches` with
      ``spec.pipeline_depth > 0`` overlaps the fused descent + disk walk of
      batch *i* (stage 2, this thread) with the coalesced first-window
@@ -180,8 +181,17 @@ class ServeStats:
     #                             (each is refetched once before raising)
     swaps: int = 0              # live index hot-swaps performed (counted on
     #                             the service's NEW epoch stats)
-    device_batches: int = 0     # batches whose resident descent ran fused
-    #                             on a device backend (pallas or jnp)
+    # resident-descent batches by the backend that served them; of the
+    # Pallas ones, those run by the interpreter (the CPU backend) ...
+    pallas_batches: int = 0
+    interpret_batches: int = 0
+    jnp_batches: int = 0
+    numpy_batches: int = 0
+    # ... and the batches a device backend sent to numpy, by reason
+    # (repro.kernels.fused_descent.prefix_gate / the query-range check)
+    numpy_width_batches: int = 0
+    numpy_key_range_batches: int = 0
+    numpy_query_range_batches: int = 0
     pipelined_batches: int = 0  # batches served through lookup_batches'
     #                             two-stage pipeline
     overlapped_preads: int = 0  # preads issued by the prefetch stage while
@@ -381,6 +391,22 @@ class ServeStats:
         return st
 
 
+def _count_backend(stats: ServeStats, used: str, reason) -> None:
+    """Attribute one resident-descent batch to the backend that served it
+    (and, for numpy standing in for a device backend, to the reason)."""
+    if used == "pallas":
+        from repro.kernels import interpret_mode
+        stats.pallas_batches += 1
+        stats.interpret_batches += interpret_mode()
+    elif used == "jnp":
+        stats.jnp_batches += 1
+    else:
+        stats.numpy_batches += 1
+        if reason is not None:
+            name = f"numpy_{reason}_batches"
+            setattr(stats, name, getattr(stats, name) + 1)
+
+
 # ---------------------------------------------------------------------------
 # ServeStats persistence (ROADMAP: serve-path autoscaling / observe→retune)
 # ---------------------------------------------------------------------------
@@ -569,8 +595,8 @@ def observed_profile_from_stats(stats: ServeStats, backing: StorageProfile,
 # ---------------------------------------------------------------------------
 #: pre-ServeSpec constructor keywords, kept as warn-once deprecation shims
 _LEGACY_KWARGS = ("cache_bytes", "cache_profile", "page_bytes",
-                  "resident_layers", "use_device", "interpret",
-                  "coalesce_gap", "persist_stats")
+                  "resident_layers", "use_device", "coalesce_gap",
+                  "persist_stats")
 
 
 def _fold_legacy_kwargs(spec, legacy: dict):
@@ -622,7 +648,7 @@ class _ServeState:
 
     __slots__ = ("path", "storage", "file_size", "meta", "tune_meta",
                  "page_bytes", "cache", "page_crcs", "resident",
-                 "prefix_lis", "prefix", "packed", "device_active",
+                 "prefix_lis", "prefix", "packed", "numpy_reason",
                  "stats", "pins", "retired")
 
     def __init__(self, path: str, storage: StorageBackend):
@@ -699,7 +725,6 @@ class IndexService:
         self.cache_profile = (PROFILES[spec.cache_profile]
                               if spec.cache_profile else None)
         self.coalesce_gap = int(spec.coalesce_gap)
-        self.interpret = spec.interpret
         self.persist_stats = bool(spec.persist_stats)
         self.backend = spec.backend
 
@@ -764,19 +789,15 @@ class IndexService:
             # walk
             st.prefix_lis = list(range(L - 1, L - n_res - 1, -1))
             st.prefix = [st.resident[li] for li in st.prefix_lis]
-            st.packed = None
-            st.device_active = False
+            # a device backend packs the prefix once per epoch; a prefix
+            # its int32 planes cannot hold serves on numpy, and every
+            # batch is counted under the reason
+            st.packed = st.numpy_reason = None
             if spec.backend != "numpy" and st.prefix:
                 from repro.kernels import fused_descent as fd
-                st.packed = fd.pack_prefix(st.prefix)
-                if st.packed is not None:
-                    try:
-                        import jax  # noqa: F401  (gated: CPU-only containers)
-                    # airlint: allow[typed-error-flow] -- import gate: the
-                    # body is 'import jax', which cannot raise a StorageError
-                    except Exception:
-                        st.packed = None
-                st.device_active = st.packed is not None
+                st.numpy_reason = fd.prefix_gate(st.prefix)
+                if st.numpy_reason is None:
+                    st.packed = fd.pack_prefix(st.prefix)
         except BaseException:
             storage.close()
             raise
@@ -876,8 +897,14 @@ class IndexService:
         return self._st.page_bytes
 
     @property
+    def device_planes(self) -> dict | None:
+        """The packed resident prefix a device backend serves from
+        (:func:`repro.kernels.fused_descent.pack_prefix`), else None."""
+        return self._st.packed
+
+    @property
     def device_active(self) -> bool:
-        return self._st.device_active
+        return self._st.packed is not None
 
     @property
     def _prefix(self) -> list:
@@ -1097,16 +1124,16 @@ class IndexService:
     # -- descent ------------------------------------------------------------
     def _descend_prefix(self, st: _ServeState, q: np.ndarray):
         """Fused walk through the whole resident prefix → float64 (L, Q)
-        lo/hi rows plus the backend that served.  Device-eligible batches
-        go through the Pallas → jnp → numpy chain; everything else is the
-        bit-exact float64 walk (= the old per-layer path exactly)."""
+        lo/hi rows, the backend that served, and why numpy served a batch
+        a device backend was asked for (else None).  Packed prefixes run
+        the requested device backend; everything else is the bit-exact
+        float64 walk (= the old per-layer path exactly)."""
         from repro.kernels import fused_descent as fd
-        if st.device_active:
+        if st.packed is not None:
             return fd.fused_descent_with_backend(
-                st.prefix, q, backend=self.backend,
-                interpret=self.interpret, packed=st.packed)
+                st.prefix, q, backend=self.backend, packed=st.packed)
         lo, hi = descend_layers(st.prefix, q)
-        return lo, hi, "numpy"
+        return lo, hi, "numpy", st.numpy_reason
 
     def _ensure_pages(self, st: _ServeState, page_ids: list,
                       deadline: float | None = None) -> dict:
@@ -1313,7 +1340,7 @@ class IndexService:
         n_res = len(st.prefix)
         if n_res:
             t0 = time.perf_counter()
-            plo, phi, used = self._descend_prefix(st, q)
+            plo, phi, used, reason = self._descend_prefix(st, q)
             dt = time.perf_counter() - t0
             walk = 0.0
             if self.profile is not None:
@@ -1337,8 +1364,7 @@ class IndexService:
             with self._mu:
                 st.stats.descent_seconds += dt
                 st.stats.walk_modeled_seconds += walk
-                if used != "numpy":
-                    st.stats.device_batches += 1
+                _count_backend(st.stats, used, reason)
             lo, hi = plo[-1], phi[-1]
         for li in range(len(metas) - n_res - 1, -1, -1):
             lo, hi = self._descend_disk(st, metas[li], lo, hi, q, deadline)
@@ -1444,7 +1470,7 @@ class IndexService:
         if n_disk <= 0 or len(q) == 0:
             return 0
         if n_res:
-            plo, phi, _ = self._descend_prefix(st, q)
+            plo, phi, _, _ = self._descend_prefix(st, q)
             lo, hi = plo[-1], phi[-1]
         else:
             lo = hi = None

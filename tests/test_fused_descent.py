@@ -1,6 +1,7 @@
 """Fused multi-layer descent: bit-identity of the numpy backend with the
 per-layer walk, device-backend step-exactness / band containment, ragged
-batches, the Pallas → jnp → numpy fallback chain, and packing guards —
+batches, the visible numpy fallback (per reason) and failures that
+propagate, the platform-chosen interpret mode, and packing guards —
 across layer-family mixes (gstep/gband/eband/rmi_leaf) and prefix depths."""
 import numpy as np
 import pytest
@@ -91,9 +92,9 @@ def test_numpy_backend_bit_identical_to_per_layer(stacks, name, depth):
     for n in (1, 7, 256, 600):
         q = qs[:n]
         want_lo, want_hi = _per_layer_walk(layers, q)
-        lo, hi, used = fd.fused_descent_with_backend(layers, q,
-                                                     backend="numpy")
-        assert used == "numpy"
+        lo, hi, used, reason = fd.fused_descent_with_backend(
+            layers, q, backend="numpy")
+        assert used == "numpy" and reason is None
         assert lo.shape == (depth, n) and hi.shape == (depth, n)
         np.testing.assert_array_equal(lo, want_lo)
         np.testing.assert_array_equal(hi, want_hi)
@@ -102,8 +103,10 @@ def test_numpy_backend_bit_identical_to_per_layer(stacks, name, depth):
 def test_empty_prefix_all_backends(stacks):
     _, qs = stacks
     for backend in ("numpy", "jnp", "pallas"):
-        lo, hi, used = fd.fused_descent_with_backend([], qs, backend=backend)
+        lo, hi, used, reason = fd.fused_descent_with_backend(
+            [], qs, backend=backend)
         assert used == "numpy"          # nothing to pack → numpy serves
+        assert reason is None           # ... and nothing was refused
         assert lo.shape == (0, len(qs))
 
 
@@ -116,10 +119,10 @@ def test_device_backends_step_exact_band_contained(stacks, name):
     for depth in (1, 2, 3):
         layers = prefixes[name][:depth]
         rlo, rhi = fd.fused_descent(layers, qs, backend="numpy")
-        plo, phi, pu = fd.fused_descent_with_backend(layers, qs,
-                                                     backend="pallas")
-        jlo, jhi, ju = fd.fused_descent_with_backend(layers, qs,
-                                                     backend="jnp")
+        plo, phi, pu, _ = fd.fused_descent_with_backend(layers, qs,
+                                                        backend="pallas")
+        jlo, jhi, ju, _ = fd.fused_descent_with_backend(layers, qs,
+                                                        backend="jnp")
         assert pu == "pallas" and ju == "jnp"
         packed = fd.pack_prefix(layers)
         for r, lay in enumerate(layers):
@@ -153,31 +156,100 @@ def test_ragged_batches_match_full_batch(stacks):
 
 
 # ---------------------------------------------------------------------------
-# fallback chain (candidate_score idiom): pallas → jnp → numpy
+# fallback: only to numpy, only for batches the int32 planes cannot hold,
+# and always with a reason; a device backend's own failure propagates
 # ---------------------------------------------------------------------------
-def test_fallback_chain_degrades_to_jnp_then_numpy(stacks, monkeypatch):
+def _oversized_prefix():
+    """A one-layer step prefix whose keys reach 2**31 (key_range)."""
+    return [{"kind": "step",
+             "keys": np.array([0, 2**31 + 5], dtype=np.uint64),
+             "pos_lo": np.array([0, 8], dtype=np.int64),
+             "pos_hi": np.array([8, 16], dtype=np.int64)}]
+
+
+def test_fallback_chain_degrades_to_jnp_then_numpy(stacks):
+    """No device chain is left: a packable batch is served by exactly the
+    requested backend, and numpy serves only unrepresentable batches,
+    naming the reason (width / key_range / query_range)."""
     prefixes, qs = stacks
     layers = prefixes["gstep3"]
     want_lo, want_hi = fd.fused_descent(layers, qs, backend="numpy")
+    for backend in ("pallas", "jnp"):
+        lo, hi, used, reason = fd.fused_descent_with_backend(
+            layers, qs, backend=backend)
+        assert (used, reason) == (backend, None)
+        np.testing.assert_array_equal(lo, want_lo)   # all-step: exact
+
+    big_q = np.array([3, 2**31 + 1], dtype=np.uint64)
+    lo, hi, used, reason = fd.fused_descent_with_backend(
+        layers, big_q, backend="pallas")
+    assert (used, reason) == ("numpy", "query_range")
+    np.testing.assert_array_equal(
+        lo, fd.fused_descent(layers, big_q, backend="numpy")[0])
+
+    lo, hi, used, reason = fd.fused_descent_with_backend(
+        _oversized_prefix(), qs, backend="pallas")
+    assert (used, reason) == ("numpy", "key_range")
+
+    n = fd.MAX_VMEM_ENTRIES + 1
+    wide = [{"kind": "step", "keys": np.arange(n, dtype=np.uint64),
+             "pos_lo": np.arange(n, dtype=np.int64),
+             "pos_hi": np.arange(1, n + 1, dtype=np.int64)}]
+    lo, hi, used, reason = fd.fused_descent_with_backend(
+        wide, qs, backend="jnp")
+    assert (used, reason) == ("numpy", "width")
+
+
+def test_kernel_failure_propagates_off_cpu(stacks, monkeypatch):
+    """On an accelerator a failing kernel raises out of the dispatcher (no
+    silent jnp/numpy stand-in), and it is asked to run compiled."""
+    import jax
 
     import repro.kernels.fused_descent.kernel as kernel
     import repro.kernels.fused_descent.ref as ref
 
+    prefixes, qs = stacks
+    layers = prefixes["step-band-step"]
+    calls = []
+
     def boom(*a, **k):
-        raise RuntimeError("backend down")
+        calls.append(k)
+        raise RuntimeError("kernel refused")
 
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     monkeypatch.setattr(kernel, "fused_descent_pallas", boom)
-    lo, hi, used = fd.fused_descent_with_backend(layers, qs,
-                                                 backend="pallas")
-    assert used == "jnp"
-    np.testing.assert_array_equal(lo, want_lo)   # all-step: jnp is exact
-
+    with pytest.raises(RuntimeError, match="kernel refused"):
+        fd.fused_descent_with_backend(layers, qs, backend="pallas")
+    assert calls == [{"interpret": False}]
     monkeypatch.setattr(ref, "fused_descent_jnp", boom)
-    lo, hi, used = fd.fused_descent_with_backend(layers, qs,
-                                                 backend="pallas")
-    assert used == "numpy"
-    np.testing.assert_array_equal(lo, want_lo)
-    np.testing.assert_array_equal(hi, want_hi)
+    with pytest.raises(RuntimeError, match="kernel refused"):
+        fd.fused_descent_with_backend(layers, qs, backend="jnp")
+
+
+@pytest.mark.parametrize("platform,interpret", [("cpu", True),
+                                                ("tpu", False),
+                                                ("gpu", False)])
+def test_interpret_mode_follows_platform(stacks, monkeypatch, platform,
+                                         interpret):
+    import jax
+
+    import repro.kernels.fused_descent.kernel as kernel
+    from repro.kernels import interpret_mode
+
+    prefixes, qs = stacks
+    seen = []
+    real = kernel.fused_descent_pallas
+
+    def spy(*a, **k):
+        seen.append(k["interpret"])
+        return real(*a, interpret=True)     # this host can only interpret
+
+    monkeypatch.setattr(jax, "default_backend", lambda: platform)
+    monkeypatch.setattr(kernel, "fused_descent_pallas", spy)
+    assert interpret_mode() is interpret
+    fd.fused_descent_with_backend(prefixes["gstep3"], qs[:8],
+                                  backend="pallas")
+    assert seen == [interpret]
 
 
 # ---------------------------------------------------------------------------
@@ -190,11 +262,20 @@ def test_pack_prefix_guards():
             "pos_lo": np.array([0, 8], dtype=np.int64),
             "pos_hi": np.array([8, 16], dtype=np.int64)}
     assert fd.pack_prefix([over]) is None
+    assert fd.prefix_gate([over]) == "key_range"
     n = fd.MAX_VMEM_ENTRIES + 1
     wide = {"kind": "step", "keys": np.arange(n, dtype=np.uint64),
             "pos_lo": np.arange(n, dtype=np.int64),
             "pos_hi": np.arange(1, n + 1, dtype=np.int64)}
     assert fd.pack_prefix([wide]) is None
+    assert fd.prefix_gate([wide]) == "width"
+    ok = {"kind": "step", "keys": np.arange(3, dtype=np.uint64),
+          "pos_lo": np.arange(3, dtype=np.int64),
+          "pos_hi": np.arange(1, 4, dtype=np.int64)}
+    assert fd.prefix_gate([ok]) is None
+    planes = fd.pack_prefix([ok, ok])
+    assert planes["kinds"].shape == (2,)
+    assert planes["keys"].shape == (2, 1, 128)      # one LANE-wide row
 
 
 # ---------------------------------------------------------------------------
@@ -232,9 +313,43 @@ def test_engine_device_backend_valid_and_attributed(stacks, tmp_path):
                                      backend="pallas")) as svc:
         assert svc.device_active
         got = svc.lookup(qs)
-        assert svc.stats.device_batches == 1
+        assert svc.stats.pallas_batches == 1
+        assert svc.stats.interpret_batches == 1     # CPU: interpreted
+        assert svc.stats.jnp_batches == svc.stats.numpy_batches == 0
         assert svc.stats.descent_seconds > 0
     # device band widening may only widen the final data window
     assert np.all(got[:, 0] <= want[:, 0]) and np.all(got[:, 1] >= want[:, 1])
     idx = np.searchsorted(D.keys, qs)
     assert np.all((got[:, 0] <= D.lo[idx]) & (got[:, 1] >= D.hi[idx]))
+
+
+def test_engine_counts_batches_per_backend_and_reason(tmp_path):
+    """ServeStats attributes each batch to the backend that served it: a
+    packable prefix on ``pallas``; a prefix with keys ≥ 2**31 on numpy
+    under ``key_range``; an out-of-range query batch under
+    ``query_range``."""
+    rng = np.random.default_rng(21)
+    small = np.unique(rng.integers(1, 2**30, 20_000).astype(np.uint64))
+    big = np.unique(rng.integers(2**31, 2**40, 20_000).astype(np.uint64))
+    got = {}
+    for name, keys in (("small", small), ("big", big)):
+        D = KeyPositions.fixed_record(keys, 16)
+        path = str(tmp_path / f"{name}.air")
+        write_index(path, _design(D, MIXES["step-band-step"]),
+                    page_bytes=1024)
+        with IndexService(path, profile=None,
+                          spec=ServeSpec(resident_layers=2,
+                                         backend="pallas")) as svc:
+            svc.lookup(rng.choice(D.keys, 300))
+            svc.lookup(rng.choice(D.keys, 40))
+            if name == "small":
+                svc.lookup(np.array([5, 2**31 + 7], dtype=np.uint64))
+            got[name] = svc.stats
+    s = got["small"]
+    assert (s.pallas_batches, s.jnp_batches, s.numpy_batches) == (2, 0, 1)
+    assert s.numpy_query_range_batches == 1
+    assert s.numpy_width_batches == s.numpy_key_range_batches == 0
+    b = got["big"]
+    assert (b.pallas_batches, b.jnp_batches, b.numpy_batches) == (0, 0, 2)
+    assert b.numpy_key_range_batches == 2
+    assert b.numpy_query_range_batches == b.numpy_width_batches == 0

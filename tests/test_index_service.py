@@ -349,7 +349,8 @@ def test_device_resident_descend_matches_numpy(tmp_path):
                                            resident_layers=2)) as svc:
         assert svc.device_active
         got = svc.lookup(qs)
-        assert svc.stats.device_batches > 0
+        assert svc.stats.pallas_batches > 0
+        assert svc.stats.numpy_batches == 0
     assert np.array_equal(got, want)
 
 

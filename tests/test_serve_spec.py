@@ -33,7 +33,7 @@ def saved(tmp_path_factory):
 def test_serve_spec_json_roundtrip():
     spec = ServeSpec(cache_bytes=(64 << 10, 1 << 20), cache_profile=None,
                      page_bytes=512, resident_layers=2, backend="pallas",
-                     interpret=True, coalesce_gap=64, persist_stats=True,
+                     coalesce_gap=64, persist_stats=True,
                      pipeline_depth=3, prefetch_layers=2)
     assert ServeSpec.from_json(spec.to_json()) == spec
     assert json.loads(spec.to_json())["cache_bytes"] == [64 << 10, 1 << 20]
@@ -80,6 +80,28 @@ def test_serve_spec_recorded_and_restored(saved, tmp_path):
     # engine alone also restores it from the meta
     with IndexService(path, profile=None) as svc:
         assert svc.spec == want
+
+
+def test_meta_with_retired_interpret_field_still_opens(saved, tmp_path):
+    """Metas written while ServeSpec still carried ``interpret`` open: the
+    key is dropped on load, the platform picks the Pallas mode."""
+    from repro.core import write_index
+    rng = np.random.default_rng(8)
+    D = KeyPositions.fixed_record(
+        np.unique(rng.integers(1, 2**30, 30_000).astype(np.uint64)), 16)
+    old = ServeSpec(resident_layers=2, backend="pallas").to_dict()
+    old["interpret"] = True
+    assert ServeSpec.from_dict(old) == ServeSpec(resident_layers=2,
+                                                 backend="pallas")
+    path = str(tmp_path / "old.air")
+    write_index(path, demo_serving_design(D), page_bytes=1024,
+                tune={"serve": old})
+    assert Index.open(path).serve_spec.backend == "pallas"
+    with IndexService(path, profile=None) as svc:
+        assert svc.spec.resident_layers == 2
+        got = svc.lookup(D.keys[:64])
+        assert svc.stats.pallas_batches == 1
+    assert np.all((got[:, 0] <= D.lo[:64]) & (got[:, 1] >= D.hi[:64]))
 
 
 def test_serve_rejects_unknown_override(saved):

@@ -304,3 +304,39 @@ def test_device_backend_tune_matches_numpy_cost():
     b = airtune(D, prof, BUILDERS, k=3)
     assert a.cost == pytest.approx(expected_latency(a.design, prof), rel=1e-9)
     assert a.cost == pytest.approx(b.cost, rel=1e-6)
+
+
+def test_candidate_score_pallas_many_candidates():
+    """C > BLOCK_C: several grid steps, each writing its own score column."""
+    from repro.kernels.candidate_score import affine_candidate_scores
+    rng = np.random.default_rng(2)
+    W = rng.uniform(16.0, 1e5, size=(61, 1024))
+    weights = rng.uniform(0.5, 3.0, size=1024)
+    ell, inv_bw = affine_coefficients(PROFILES["azure_ssd"])
+    ref = affine_candidate_scores(W, weights, ell, inv_bw, backend="numpy")
+    got = affine_candidate_scores(W, weights, ell, inv_bw, backend="pallas")
+    assert got.shape == (61,)
+    np.testing.assert_allclose(got, ref, rtol=3e-5)
+
+
+def test_candidate_score_failure_propagates(monkeypatch):
+    """A refused scoring kernel raises out of the dispatcher on an
+    accelerator; it is never swapped for jnp or numpy in silence."""
+    import jax
+
+    import repro.kernels.candidate_score.kernel as kernel
+    from repro.kernels.candidate_score import candidate_scores
+
+    calls = []
+
+    def boom(*a, **k):
+        calls.append(k["interpret"])
+        raise RuntimeError("kernel refused")
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(kernel, "affine_scores_pallas", boom)
+    W = np.full((3, 40), 100.0)
+    with pytest.raises(RuntimeError, match="kernel refused"):
+        candidate_scores(W, np.ones(40), PROFILES["azure_ssd"],
+                         backend="pallas")
+    assert calls == [False]

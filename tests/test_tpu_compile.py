@@ -1,0 +1,74 @@
+"""The serving and tuning kernels compile for a TPU v5e chip.
+
+Interpret mode accepts block shapes the chip's compiler refuses, so these
+tests lower each kernel at serving widths for a described (not attached)
+``v5e:2x2`` topology and compile it with the installed TPU compiler.
+Nothing runs.  The topology is described inside a fixture, never at
+import: the TPU library admits one process at a time, and every test
+worker imports this file.  All compiles stay in this one file so that one
+worker holds that library.
+"""
+import os
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels.candidate_score.kernel import affine_scores_pallas
+from repro.kernels.fused_descent.kernel import fused_descent_pallas
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def no_persistent_cache():
+    """A compile for a described chip is written to the persistent cache
+    but cannot be read back without one: keep it out of the cache."""
+    from jax.experimental.compilation_cache import compilation_cache
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    compilation_cache.reset_cache()
+
+
+def _shape(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+@pytest.mark.parametrize("L,P,Q", [(1, 128, 256), (3, 4096, 4096),
+                                   (4, 1024, 65536)])
+def test_fused_descent_compiles_for_v5e(one_chip, no_persistent_cache,
+                                        L, P, Q):
+    args = ([_shape((1, Q), jnp.int32, one_chip),
+             _shape((L,), jnp.int32, one_chip)]
+            + [_shape((L, 1, P), jnp.int32, one_chip)] * 3
+            + [_shape((L, 1, P), jnp.float32, one_chip)] * 4)
+    compiled = fused_descent_pallas.lower(*args, interpret=False).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_candidate_score_compiles_for_v5e(one_chip, no_persistent_cache):
+    C, S = 64, 1024
+    compiled = affine_scores_pallas.lower(
+        _shape((C, S), jnp.float32, one_chip),
+        _shape((S,), jnp.float32, one_chip),
+        ell=1e-4, inv_bw=1e-9, interpret=False).compile()
+    assert "tpu_custom_call" in compiled.as_text()
