@@ -1,0 +1,6 @@
+"""Median latency of the lookups due in the window, due time to answer (ms)."""
+from readings import latency_ms
+
+
+def read(rec):
+    return latency_ms(rec, 50)
