@@ -88,11 +88,10 @@ pipe = reopened.serve(spec=ServeSpec(cache_bytes=(8 << 10,),
                                      pipeline_depth=2, prefetch_layers=2))
 batches = [rng.choice(D.keys, 400) for _ in range(4)]
 pipe.lookup_batches(batches)
-roof = pipe.stats.roofline()
 print(f"pipelined {pipe.stats.pipelined_batches} batches, "
       f"{pipe.stats.overlapped_preads} preads overlapped with descent; "
-      f"roofline: {roof['bound']}-bound "
-      f"(io_fraction={roof['io_fraction']:.2f})")
+      f"descent {pipe.stats.descent_seconds * 1e3:.2f}ms measured, "
+      f"preads {pipe.stats.pread_modeled_seconds * 1e3:.2f}ms modeled")
 pipe.close()
 
 print("== re-tune FOR the cache (CachedProfile via Index.retune) ==")

@@ -19,6 +19,8 @@ from __future__ import annotations
 
 import numpy as np
 
+from repro.spans import span
+
 from . import ref
 
 MAX_VMEM_ENTRIES = 4096  # the fused kernel keeps one layer plane in VMEM
@@ -28,6 +30,8 @@ KEY_PAD = np.iinfo(np.int32).max
 # device backends index with int32; KEY_PAD must stay strictly greater than
 # every real key AND every query, hence the -1
 _I32_LIM = 2**31 - 1
+# the kernel's arguments after the queries, in order
+_PLANES = ("kinds", "keys", "pos_lo", "pos_hi", "x1", "y1", "m", "delta")
 
 
 def band_f32_slack(y1, m, x1) -> np.ndarray:
@@ -102,43 +106,61 @@ def pack_prefix(layers) -> dict | None:
             "x1": x1, "y1": y1, "m": m, "delta": delta}
 
 
-def _device_descent(planes: dict, q: np.ndarray, backend: str):
-    """One device dispatch over packed planes → float64 (L, Q) rows."""
+def _device_descent(planes: dict, q: np.ndarray, backend: str,
+                    timings: dict | None = None):
+    """One device dispatch over packed planes → float64 (L, Q) rows.
+
+    Three spans split the host's time: ``stage`` casts and pads the
+    queries and hands them and the planes to the device, ``launch``
+    enqueues the kernel and the slices of its padded output, ``collect``
+    waits for the device and copies the rows back.  ``timings``, when
+    given, receives each phase's seconds and ``h2d_bytes``, the bytes of
+    every host array handed to the device at the dtype sent."""
     import jax.numpy as jnp
 
     from repro.kernels import interpret_mode
 
     from . import kernel as K
 
-    qi = jnp.asarray(q.astype(np.int64), jnp.int32)
-    if backend == "jnp":
-        lo, hi = ref.fused_descent_jnp(planes, qi)
-    elif backend == "pallas":
-        nq = qi.shape[0]
-        pad = (-nq) % K.BLOCK_Q
-        if pad:
-            qi = jnp.concatenate([qi, jnp.full((pad,), qi[-1], qi.dtype)])
-        jplanes = [jnp.asarray(planes[k]) for k in
-                   ("kinds", "keys", "pos_lo", "pos_hi", "x1", "y1", "m",
-                    "delta")]
-        lo, hi = K.fused_descent_pallas(qi[None, :], *jplanes,
-                                        interpret=interpret_mode())
-        lo, hi = lo[:, 0, :nq], hi[:, 0, :nq]
-    else:
+    if backend not in ("jnp", "pallas"):
         raise ValueError(f"unknown device backend {backend!r}")
-    return (np.asarray(lo, dtype=np.float64),
-            np.asarray(hi, dtype=np.float64))
+    nq = len(q)
+    with span("airindex.descent.stage") as stage:
+        qh = q.astype(np.int32)     # every query is below 2**31 - 1 (gated)
+        pad = (-nq) % K.BLOCK_Q if backend == "pallas" else 0
+        if pad:
+            qh = np.concatenate([qh, np.full(pad, qh[-1], np.int32)])
+        sent = [qh] + [planes[k] for k in _PLANES]
+        qi, *dev = [jnp.asarray(a) for a in sent]
+    with span("airindex.descent.launch") as launch:
+        if backend == "jnp":
+            lo, hi = ref.fused_descent_jnp(dict(zip(_PLANES, dev)), qi)
+        else:
+            lo, hi = K.fused_descent_pallas(qi[None, :], *dev,
+                                            interpret=interpret_mode())
+            lo, hi = lo[:, 0, :nq], hi[:, 0, :nq]
+    with span("airindex.descent.collect") as collect:
+        out = (np.asarray(lo, dtype=np.float64),
+               np.asarray(hi, dtype=np.float64))
+    if timings is not None:
+        timings.update(stage_seconds=stage.seconds,
+                       launch_seconds=launch.seconds,
+                       collect_seconds=collect.seconds,
+                       h2d_bytes=sum(a.nbytes for a in sent))
+    return out
 
 
 def fused_descent_with_backend(layers, queries, *, backend: str = "pallas",
-                               packed=None):
+                               packed=None, timings: dict | None = None):
     """Like :func:`fused_descent` but also reports who served and why:
     ``(lo, hi, backend_used, numpy_reason)``.  ``numpy_reason`` is None
     unless a device backend was requested and numpy served the batch:
     then it is ``"width"`` or ``"key_range"`` (:func:`prefix_gate`) or
     ``"query_range"`` (a query reaches 2**31 - 1).  An empty prefix or
     batch has nothing to descend and serves on numpy with no reason.  A
-    device backend's own failure propagates."""
+    device backend's own failure propagates.  ``timings``, when given,
+    receives a device dispatch's phase seconds and ``h2d_bytes``
+    (:func:`_device_descent`); a batch numpy serves leaves it empty."""
     q = np.atleast_1d(np.asarray(queries, dtype=np.uint64))
     reason = None
     if backend != "numpy" and layers and len(q):
@@ -148,7 +170,7 @@ def fused_descent_with_backend(layers, queries, *, backend: str = "pallas",
         if reason is None and int(q.max()) >= _I32_LIM:
             reason = "query_range"
         if reason is None:
-            lo, hi = _device_descent(packed, q, backend)
+            lo, hi = _device_descent(packed, q, backend, timings)
             return lo, hi, backend, None
     lo, hi = ref.fused_descent_ref(layers, q)
     return lo, hi, "numpy", reason

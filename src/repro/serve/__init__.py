@@ -5,12 +5,10 @@ from .index_service import (IndexService, ServeStats, TieredBlockCache,
                             cacheable_working_set, load_serve_stats,
                             load_stats_history, observed_profile_from_stats,
                             save_stats_snapshot, stats_path)
-from .serve_step import make_prefill_step, make_decode_step
 
 __all__ = ["IndexService", "ServeStats", "TieredBlockCache",
            "cacheable_working_set", "load_serve_stats", "load_stats_history",
            "observed_profile_from_stats", "save_stats_snapshot", "stats_path",
-           "make_prefill_step", "make_decode_step",
            "StorageBackend", "FileBackend", "FaultInjectingBackend",
            "StorageError", "ReadError", "CorruptPageError",
            "DeadlineExceededError", "pread_full"]

@@ -34,9 +34,13 @@ pre-spec keyword surface survives as warn-once deprecation shims.
 Per-layer descent is the same :mod:`repro.core.descent` step used by
 ``lookup_batch`` and ``SerializedIndex``, so all three paths agree
 bit-for-bit.  Observed hit rates feed back into tuning via
-:meth:`IndexService.cached_profile` (→ :class:`repro.core.CachedProfile`);
-:meth:`ServeStats.roofline` attributes served time to compute vs I/O so
-the serve bench can trend which side of the roofline the engine sits on.
+:meth:`IndexService.cached_profile` (→ :class:`repro.core.CachedProfile`).
+
+Every layer boundary of a served lookup is a :func:`repro.spans.span`
+(``airindex.lookup`` → ``airindex.descent`` with its ``stage`` /
+``launch`` / ``collect`` phases, and ``airindex.walk`` →
+``airindex.walk.fetch``): on a profiler trace beside the device's
+operations, and summed into :class:`ServeStats` under the lock.
 """
 from __future__ import annotations
 
@@ -59,6 +63,7 @@ from repro.core.storage import (CachedProfile, DistributionalProfile,
                                 MeasuredProfile, PROFILES, StorageProfile)
 from repro.serve.backend import (CorruptPageError, DeadlineExceededError,
                                  FileBackend, ReadError, StorageBackend)
+from repro.spans import span
 
 DEFAULT_PAGE_BYTES = 4096
 
@@ -199,13 +204,23 @@ class ServeStats:
     modeled_seconds: float = 0.0   # Σ T(Δ) under the configured profile
     open_modeled_seconds: float = 0.0  # the open-time share of the above
     data_modeled_seconds: float = 0.0  # Σ T(hi−lo) of returned data ranges
-    # roofline attribution (see .roofline()): measured wall inside the
-    # fused resident descent (stage-2 compute) vs Σ T(run) of every pread
-    # actually issued under the deployment profile (serving I/O; open-time
-    # resident loads excluded) — plus the prefetch stage's own wall
+    # Σ T(run) of every pread actually issued under the deployment
+    # profile (serving I/O; open-time resident loads excluded)
     pread_modeled_seconds: float = 0.0
+    # measured host seconds of the serving thread's spans (repro.spans),
+    # each nested in the one above it: lookup ⊇ descent ⊇ its three
+    # device-dispatch phases; lookup ⊇ walk ⊇ walk_fetch (cache probes
+    # plus coalesced preads)
+    lookup_seconds: float = 0.0
     descent_seconds: float = 0.0
-    prefetch_seconds: float = 0.0
+    descent_stage_seconds: float = 0.0    # query cast/pad + upload
+    descent_launch_seconds: float = 0.0   # kernel enqueue + output slices
+    descent_collect_seconds: float = 0.0  # wait + float64 copy-back
+    h2d_bytes: int = 0          # host arrays handed to the device
+    walk_seconds: float = 0.0
+    walk_fetch_seconds: float = 0.0
+    walk_windows: int = 0       # distinct windows the disk walk scanned
+    prefetch_seconds: float = 0.0  # the prefetch stage's own wall
     overlapped_pread_seconds: float = 0.0  # measured wall of tagged preads
     # what the *uncached* Alg. 1 walk (lookup_serialized) would pay for the
     # same traffic under the configured profile: per query, full price for
@@ -320,25 +335,6 @@ class ServeStats:
         pos = (np.cumsum(w) - 0.5 * w) / w.sum()
         return float(np.interp(float(p), pos, vals))
 
-    def roofline(self) -> dict:
-        """Compute-vs-I/O attribution of served traffic: measured wall
-        inside the fused resident descent (stage-2 compute) vs the modeled
-        cost ``Σ T(run)`` of every pread actually issued under the
-        deployment tier (overlapped or not; open-time loads excluded).
-        ``bound`` names the roofline side — ``"pread"`` is the goal state,
-        the regime the paper's storage-aware tuning optimizes for.  The
-        serve bench trends this dict per PR (``BENCH_serve.json``)."""
-        compute = float(self.descent_seconds)
-        io = float(self.pread_modeled_seconds)
-        total = compute + io
-        return {
-            "compute_seconds": compute,
-            "io_seconds": io,
-            "io_fraction": (io / total) if total > 0 else None,
-            "bound": (("pread" if io >= compute else "descent")
-                      if total > 0 else None),
-        }
-
     def snapshot(self) -> dict:
         d = dataclasses.asdict(self)
         d["read_samples"] = [[int(r[0]), float(r[1]), bool(r[2]), bool(r[3])]
@@ -346,7 +342,6 @@ class ServeStats:
         d["lookup_samples"] = [[int(r[0]), float(r[1])]
                                for r in self.lookup_samples]
         d["hit_rate"] = self.hit_rate
-        d["roofline"] = self.roofline()
         # derived, human-readable tail estimates (ignored on load)
         d["lookup_p50_seconds"] = self.lookup_quantile(0.5)
         d["lookup_p99_seconds"] = self.lookup_quantile(0.99)
@@ -1122,16 +1117,19 @@ class IndexService:
         return raw
 
     # -- descent ------------------------------------------------------------
-    def _descend_prefix(self, st: _ServeState, q: np.ndarray):
+    def _descend_prefix(self, st: _ServeState, q: np.ndarray,
+                        timings: dict | None = None):
         """Fused walk through the whole resident prefix → float64 (L, Q)
         lo/hi rows, the backend that served, and why numpy served a batch
         a device backend was asked for (else None).  Packed prefixes run
         the requested device backend; everything else is the bit-exact
-        float64 walk (= the old per-layer path exactly)."""
+        float64 walk (= the old per-layer path exactly).  ``timings``
+        receives a device dispatch's phase seconds and bytes sent."""
         from repro.kernels import fused_descent as fd
         if st.packed is not None:
             return fd.fused_descent_with_backend(
-                st.prefix, q, backend=self.backend, packed=st.packed)
+                st.prefix, q, backend=self.backend, packed=st.packed,
+                timings=timings)
         lo, hi = descend_layers(st.prefix, q)
         return lo, hi, "numpy", st.numpy_reason
 
@@ -1249,7 +1247,11 @@ class IndexService:
             need: set = set()
             for x, y in zip(pa.tolist(), pb.tolist()):
                 need.update(range(x, y))
-            pages = self._ensure_pages(st, sorted(need), deadline)
+            with span("airindex.walk.fetch") as fetch:
+                pages = self._ensure_pages(st, sorted(need), deadline)
+            with self._mu:
+                st.stats.walk_fetch_seconds += fetch.seconds
+                st.stats.walk_windows += len(ab)
             still = []
             for ui in range(len(ab)):
                 base = int(pa[ui]) * P
@@ -1306,14 +1308,14 @@ class IndexService:
         triggers shares one absolute deadline.
         """
         st = self._pin()
-        t0 = time.perf_counter()
-        try:
-            out = self._lookup_pinned(st, queries)
-        finally:
-            self._unpin(st)
-        wall = time.perf_counter() - t0
+        with span("airindex.lookup") as call:
+            try:
+                out = self._lookup_pinned(st, queries)
+            finally:
+                self._unpin(st)
         with self._mu:
-            st.stats.record_lookup(len(out), wall)
+            st.stats.lookup_seconds += call.seconds
+            st.stats.record_lookup(len(out), call.seconds)
         return out
 
     def _lookup_pinned(self, st: _ServeState, queries) -> np.ndarray:
@@ -1339,9 +1341,9 @@ class IndexService:
         lo = hi = None
         n_res = len(st.prefix)
         if n_res:
-            t0 = time.perf_counter()
-            plo, phi, used, reason = self._descend_prefix(st, q)
-            dt = time.perf_counter() - t0
+            phases: dict = {}
+            with span("airindex.descent") as descent:
+                plo, phi, used, reason = self._descend_prefix(st, q, phases)
             walk = 0.0
             if self.profile is not None:
                 for r, li in enumerate(st.prefix_lis):
@@ -1362,12 +1364,23 @@ class IndexService:
                         walk += float(np.sum(
                             self.profile((wb - wa).astype(np.float64))))
             with self._mu:
-                st.stats.descent_seconds += dt
+                st.stats.descent_seconds += descent.seconds
+                st.stats.descent_stage_seconds += phases.get(
+                    "stage_seconds", 0.0)
+                st.stats.descent_launch_seconds += phases.get(
+                    "launch_seconds", 0.0)
+                st.stats.descent_collect_seconds += phases.get(
+                    "collect_seconds", 0.0)
+                st.stats.h2d_bytes += phases.get("h2d_bytes", 0)
                 st.stats.walk_modeled_seconds += walk
                 _count_backend(st.stats, used, reason)
             lo, hi = plo[-1], phi[-1]
         for li in range(len(metas) - n_res - 1, -1, -1):
-            lo, hi = self._descend_disk(st, metas[li], lo, hi, q, deadline)
+            with span("airindex.walk") as walked:
+                lo, hi = self._descend_disk(st, metas[li], lo, hi, q,
+                                            deadline)
+            with self._mu:
+                st.stats.walk_seconds += walked.seconds
         lo = np.maximum(np.asarray(lo, dtype=np.int64), 0)
         hi = np.minimum(np.maximum(np.asarray(hi, dtype=np.int64), lo + 1),
                         st.meta.data_size)

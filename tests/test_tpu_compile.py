@@ -8,7 +8,9 @@ import: the TPU library admits one process at a time, and every test
 worker imports this file.  All compiles stay in this one file so that one
 worker holds that library.
 """
+import functools
 import os
+import re
 
 import pytest
 
@@ -17,7 +19,8 @@ import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
 from repro.kernels.candidate_score.kernel import affine_scores_pallas
-from repro.kernels.fused_descent.kernel import fused_descent_pallas
+from repro.kernels.fused_descent.kernel import (KERNEL_NAME,
+                                                fused_descent_pallas)
 
 
 @pytest.fixture(scope="module")
@@ -63,6 +66,28 @@ def test_fused_descent_compiles_for_v5e(one_chip, no_persistent_cache,
             + [_shape((L, 1, P), jnp.float32, one_chip)] * 4)
     compiled = fused_descent_pallas.lower(*args, interpret=False).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_fused_descent_custom_call_keeps_its_name(one_chip,
+                                                  no_persistent_cache):
+    """A profiler trace names the kernel's events by its custom call in the
+    compiled HLO: the ``pallas_call``'s own ``name=``, whatever the jitted
+    wrapper around it is called."""
+    L, P, Q = 2, 1536, 1024
+    args = ([_shape((1, Q), jnp.int32, one_chip),
+             _shape((L,), jnp.int32, one_chip)]
+            + [_shape((L, 1, P), jnp.int32, one_chip)] * 3
+            + [_shape((L, 1, P), jnp.float32, one_chip)] * 4)
+
+    @functools.partial(jax.jit, static_argnames=("interpret",))
+    def renamed_wrapper(*a, interpret):
+        return fused_descent_pallas.__wrapped__(*a, interpret=interpret)
+
+    text = renamed_wrapper.lower(*args, interpret=False).compile().as_text()
+    calls = [ln.split(" = ", 1)[0].split()[-1] for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    assert len(calls) == 1, calls
+    assert re.match(rf"^%{KERNEL_NAME}\b", calls[0]), calls
 
 
 def test_candidate_score_compiles_for_v5e(one_chip, no_persistent_cache):
