@@ -124,3 +124,17 @@ def fused_descent_pallas(queries, kinds, keys, pos_lo, pos_hi, x1, y1, m,
         # events in a profiler trace), whatever the wrapper is called
         name=KERNEL_NAME,
     )(kinds, queries, keys, pos_lo, pos_hi, x1, y1, m, delta)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def fused_descent_windows(queries, kinds, keys, pos_lo, pos_hi, x1, y1, m,
+                          delta, *, interpret=False):
+    """One batch in one compiled call: queries (Q,) int32, Q a multiple of
+    BLOCK_Q, and the planes of :func:`fused_descent_pallas` → (2, L, Q)
+    int32, ``lo`` stacked over ``hi``, so both come back in one copy.  The
+    (1, Q) reshape and the output assembly compile into the kernel's
+    program: one executable per (L, P, Q)."""
+    lo, hi = fused_descent_pallas(queries[None, :], kinds, keys, pos_lo,
+                                  pos_hi, x1, y1, m, delta,
+                                  interpret=interpret)
+    return jnp.stack([lo[:, 0], hi[:, 0]])

@@ -14,6 +14,11 @@ is never swallowed.  Only batches the int32 planes cannot represent go to
 numpy, and :func:`fused_descent_with_backend` names why (``"width"``,
 ``"key_range"``, ``"query_range"``).  Pallas runs in interpret mode on the
 CPU and compiled everywhere else (:func:`repro.kernels.interpret_mode`).
+
+A long-lived caller packs its prefix once (:func:`pack_prefix`), puts the
+planes on the device once (:func:`upload_planes`) and passes them as
+``resident=``: each batch then sends only its queries, makes one compiled
+call and copies lo and hi back in one transfer.
 """
 from __future__ import annotations
 
@@ -106,17 +111,36 @@ def pack_prefix(layers) -> dict | None:
             "x1": x1, "y1": y1, "m": m, "delta": delta}
 
 
-def _device_descent(planes: dict, q: np.ndarray, backend: str,
-                    timings: dict | None = None):
-    """One device dispatch over packed planes → float64 (L, Q) rows.
+def upload_planes(packed: dict, backend: str):
+    """Put a :func:`pack_prefix` result on the device for ``backend`` →
+    ``(planes, bytes sent)``: the same keys, each a device array, but for
+    the jnp backend's ``kinds``, which stays on the host (it branches on it
+    in Python).  A serving epoch uploads once and passes ``planes`` as
+    ``resident=`` with every batch."""
+    import jax
+
+    if backend not in ("jnp", "pallas"):
+        raise ValueError(f"unknown device backend {backend!r}")
+    names = _PLANES[1:] if backend == "jnp" else _PLANES
+    planes = dict(packed)
+    planes.update(zip(names, jax.device_put([packed[k] for k in names])))
+    return planes, sum(packed[k].nbytes for k in names)
+
+
+def _device_descent(packed: dict, q: np.ndarray, backend: str,
+                    timings: dict | None = None, resident: dict | None = None):
+    """One device dispatch → float64 (L, Q) rows.
 
     Three spans split the host's time: ``stage`` casts and pads the
-    queries and hands them and the planes to the device, ``launch``
-    enqueues the kernel and the slices of its padded output, ``collect``
-    waits for the device and copies the rows back.  ``timings``, when
-    given, receives each phase's seconds and ``h2d_bytes``, the bytes of
-    every host array handed to the device at the dtype sent."""
-    import jax.numpy as jnp
+    queries (and uploads the planes when no ``resident`` copy is given);
+    ``launch`` is the one compiled call,
+    :func:`kernel.fused_descent_windows`, which takes the host queries and
+    sends them itself (the jnp backend's ops run eagerly); ``collect``
+    waits for the device, copies lo and hi back together and widens them
+    to float64.  ``timings``, when given, receives each phase's seconds
+    and ``h2d_bytes``, the bytes of every host array handed to the
+    device at the dtype sent."""
+    import jax
 
     from repro.kernels import interpret_mode
 
@@ -130,35 +154,42 @@ def _device_descent(planes: dict, q: np.ndarray, backend: str,
         pad = (-nq) % K.BLOCK_Q if backend == "pallas" else 0
         if pad:
             qh = np.concatenate([qh, np.full(pad, qh[-1], np.int32)])
-        sent = [qh] + [planes[k] for k in _PLANES]
-        qi, *dev = [jnp.asarray(a) for a in sent]
+        sent = qh.nbytes
+        if resident is None:        # a one-shot caller's planes go up too
+            resident, plane_bytes = upload_planes(packed, backend)
+            sent += plane_bytes
     with span("airindex.descent.launch") as launch:
         if backend == "jnp":
-            lo, hi = ref.fused_descent_jnp(dict(zip(_PLANES, dev)), qi)
+            out = ref.fused_descent_jnp(resident, qh)
         else:
-            lo, hi = K.fused_descent_pallas(qi[None, :], *dev,
-                                            interpret=interpret_mode())
-            lo, hi = lo[:, 0, :nq], hi[:, 0, :nq]
+            out = K.fused_descent_windows(
+                qh, *(resident[k] for k in _PLANES),
+                interpret=interpret_mode())
     with span("airindex.descent.collect") as collect:
-        out = (np.asarray(lo, dtype=np.float64),
-               np.asarray(hi, dtype=np.float64))
+        # (2, L, Q) int32 in one copy; the jnp backend's (lo, hi) pair
+        host = np.asarray(out) if backend == "pallas" else jax.device_get(out)
+        lo = np.asarray(host[0][:, :nq], dtype=np.float64)
+        hi = np.asarray(host[1][:, :nq], dtype=np.float64)
     if timings is not None:
         timings.update(stage_seconds=stage.seconds,
                        launch_seconds=launch.seconds,
                        collect_seconds=collect.seconds,
-                       h2d_bytes=sum(a.nbytes for a in sent))
-    return out
+                       h2d_bytes=sent)
+    return lo, hi
 
 
 def fused_descent_with_backend(layers, queries, *, backend: str = "pallas",
-                               packed=None, timings: dict | None = None):
+                               packed=None, resident=None,
+                               timings: dict | None = None):
     """Like :func:`fused_descent` but also reports who served and why:
     ``(lo, hi, backend_used, numpy_reason)``.  ``numpy_reason`` is None
     unless a device backend was requested and numpy served the batch:
     then it is ``"width"`` or ``"key_range"`` (:func:`prefix_gate`) or
     ``"query_range"`` (a query reaches 2**31 - 1).  An empty prefix or
     batch has nothing to descend and serves on numpy with no reason.  A
-    device backend's own failure propagates.  ``timings``, when given,
+    device backend's own failure propagates.  ``resident`` is ``packed``
+    already on the device (:func:`upload_planes`, same backend); without
+    it the planes go up with the batch.  ``timings``, when given,
     receives a device dispatch's phase seconds and ``h2d_bytes``
     (:func:`_device_descent`); a batch numpy serves leaves it empty."""
     q = np.atleast_1d(np.asarray(queries, dtype=np.uint64))
@@ -170,7 +201,7 @@ def fused_descent_with_backend(layers, queries, *, backend: str = "pallas",
         if reason is None and int(q.max()) >= _I32_LIM:
             reason = "query_range"
         if reason is None:
-            lo, hi = _device_descent(packed, q, backend, timings)
+            lo, hi = _device_descent(packed, q, backend, timings, resident)
             return lo, hi, backend, None
     lo, hi = ref.fused_descent_ref(layers, q)
     return lo, hi, "numpy", reason
@@ -186,7 +217,8 @@ def fused_descent(layers, queries, *, backend: str = "pallas", packed=None):
     :func:`repro.core.descent.descend_layers` walk; device backends keep
     step rows exact and widen band rows by the f32 δ slack.  ``packed``
     lets long-lived callers reuse one :func:`pack_prefix` result across
-    batches.
+    batches; its planes go up to the device with each call (a serving
+    epoch keeps them there: :func:`upload_planes`).
     """
     lo, hi, _, _ = fused_descent_with_backend(layers, queries,
                                               backend=backend, packed=packed)

@@ -213,10 +213,14 @@ class ServeStats:
     # plus coalesced preads)
     lookup_seconds: float = 0.0
     descent_seconds: float = 0.0
-    descent_stage_seconds: float = 0.0    # query cast/pad + upload
-    descent_launch_seconds: float = 0.0   # kernel enqueue + output slices
-    descent_collect_seconds: float = 0.0  # wait + float64 copy-back
-    h2d_bytes: int = 0          # host arrays handed to the device
+    descent_stage_seconds: float = 0.0    # query cast and pad
+    descent_launch_seconds: float = 0.0   # the one compiled call
+    descent_collect_seconds: float = 0.0  # wait, copy-back, float64
+    h2d_bytes: int = 0          # host arrays handed to the device: the
+    #                             resident planes once per epoch, then
+    #                             each batch's queries
+    plane_uploads: int = 0      # resident-prefix uploads: one per epoch
+    #                             (carried forward, like ``swaps``)
     walk_seconds: float = 0.0
     walk_fetch_seconds: float = 0.0
     walk_windows: int = 0       # distinct windows the disk walk scanned
@@ -635,16 +639,17 @@ def _fold_legacy_kwargs(spec, legacy: dict):
 
 class _ServeState:
     """One serving *epoch*: everything :meth:`IndexService.swap` replaces
-    atomically — the storage backend, decoded meta, resident prefix,
-    block cache, page-CRC table, and that epoch's :class:`ServeStats`.
-    Lookups pin the state for their whole batch (``pins`` refcount under
-    the service lock), so a swap never closes a backend mid-descent and
-    no batch ever mixes bytes from two index files."""
+    atomically — the storage backend, decoded meta, resident prefix (and
+    its packed planes on the device), block cache, page-CRC table, and
+    that epoch's :class:`ServeStats`.  Lookups pin the state for their
+    whole batch (``pins`` refcount under the service lock), so a swap
+    never closes a backend or drops planes mid-descent and no batch ever
+    mixes bytes from two index files."""
 
     __slots__ = ("path", "storage", "file_size", "meta", "tune_meta",
                  "page_bytes", "cache", "page_crcs", "resident",
-                 "prefix_lis", "prefix", "packed", "numpy_reason",
-                 "stats", "pins", "retired")
+                 "prefix_lis", "prefix", "packed", "dev_planes",
+                 "numpy_reason", "stats", "pins", "retired")
 
     def __init__(self, path: str, storage: StorageBackend):
         self.path = path
@@ -652,6 +657,13 @@ class _ServeState:
         self.stats = ServeStats()
         self.pins = 0
         self.retired = False
+        self.packed = self.dev_planes = None
+
+    def release(self) -> None:
+        """Close the backend and let go of the device planes: called once
+        the epoch is retired and its last batch has unpinned it."""
+        self.storage.close()
+        self.dev_planes = None
 
 
 class IndexService:
@@ -784,15 +796,21 @@ class IndexService:
             # walk
             st.prefix_lis = list(range(L - 1, L - n_res - 1, -1))
             st.prefix = [st.resident[li] for li in st.prefix_lis]
-            # a device backend packs the prefix once per epoch; a prefix
-            # its int32 planes cannot hold serves on numpy, and every
-            # batch is counted under the reason
-            st.packed = st.numpy_reason = None
+            # a device backend packs the prefix and puts its planes on
+            # the device once per epoch, so a batch sends only its
+            # queries; a prefix the int32 planes cannot hold serves on
+            # numpy, and every batch is counted under the reason
+            st.numpy_reason = None
             if spec.backend != "numpy" and st.prefix:
                 from repro.kernels import fused_descent as fd
                 st.numpy_reason = fd.prefix_gate(st.prefix)
                 if st.numpy_reason is None:
                     st.packed = fd.pack_prefix(st.prefix)
+                    st.dev_planes, sent = fd.upload_planes(st.packed,
+                                                           spec.backend)
+                    with self._mu:
+                        st.stats.plane_uploads += 1
+                        st.stats.h2d_bytes += sent
         except BaseException:
             storage.close()
             raise
@@ -894,7 +912,8 @@ class IndexService:
     @property
     def device_planes(self) -> dict | None:
         """The packed resident prefix a device backend serves from
-        (:func:`repro.kernels.fused_descent.pack_prefix`), else None."""
+        (:func:`repro.kernels.fused_descent.pack_prefix`, host arrays;
+        the epoch keeps a copy on the device), else None."""
         return self._st.packed
 
     @property
@@ -934,24 +953,26 @@ class IndexService:
             st.pins -= 1
             dead = st.retired and st.pins == 0
         if dead:
-            st.storage.close()
+            st.release()
 
     def swap(self, path: str, *, spec=None) -> None:
         """Hot-swap serving to ``path`` (e.g. a freshly retuned index)
         under live traffic.  The new file is fully opened — meta, CRC
-        table, resident prefix, cold cache, fresh :class:`ServeStats` —
-        *before* the switch, and the switch itself is one pointer move
-        under the service lock: batches already in flight pinned the old
-        epoch at entry and finish on its backend + cache; batches
-        arriving after ``swap`` returns serve entirely from the new one.
-        No result ever mixes bytes of the two files.  The old epoch's
-        stats are persisted first (``persist_stats=True``) and its
-        backend closes when the last in-flight batch unpins it.  With
-        ``spec=None`` the service keeps its current (deployment) spec;
-        fresh-epoch stats keep observed_profile() honest for the new
-        design, carrying only the ``swaps`` counter forward.  This is the
-        closing move of the ROADMAP's observe → drift → retune loop —
-        see ``examples/retune_daemon.py``."""
+        table, resident prefix and its planes uploaded to the device,
+        cold cache, fresh :class:`ServeStats` — *before* the switch, and
+        the switch itself is one pointer move under the service lock:
+        batches already in flight pinned the old epoch at entry and
+        finish on its backend, planes and cache; batches arriving after
+        ``swap`` returns serve entirely from the new one.  No result ever
+        mixes bytes of the two files.  The old epoch's stats are
+        persisted first (``persist_stats=True``); its backend closes, and
+        its device planes go, when the last in-flight batch unpins it.
+        With ``spec=None`` the service keeps its current (deployment)
+        spec; fresh-epoch stats keep observed_profile() honest for the
+        new design, carrying only the ``swaps`` and ``plane_uploads``
+        counters forward.  This is the closing move of the ROADMAP's
+        observe → drift → retune loop — see
+        ``examples/retune_daemon.py``."""
         if self._state is None:
             raise RuntimeError("swap() on a closed IndexService")
         st_new, resolved = self._open_state(
@@ -962,6 +983,7 @@ class IndexService:
                 st_new.storage.close()
                 raise RuntimeError("swap() on a closed IndexService")
             st_new.stats.swaps = old.stats.swaps + 1
+            st_new.stats.plane_uploads += old.stats.plane_uploads
             self._state = st_new
             self.path = path
             old.retired = True
@@ -976,7 +998,7 @@ class IndexService:
             except OSError:
                 pass
         if dead:
-            old.storage.close()
+            old.release()
 
     # -- lifecycle ----------------------------------------------------------
     def close(self) -> None:
@@ -1005,7 +1027,7 @@ class IndexService:
             except OSError:
                 pass          # a read-only deployment must still close
         if dead:              # stragglers (if any) close on last unpin
-            st.storage.close()
+            st.release()
 
     def __enter__(self) -> "IndexService":
         return self
@@ -1122,14 +1144,15 @@ class IndexService:
         """Fused walk through the whole resident prefix → float64 (L, Q)
         lo/hi rows, the backend that served, and why numpy served a batch
         a device backend was asked for (else None).  Packed prefixes run
-        the requested device backend; everything else is the bit-exact
-        float64 walk (= the old per-layer path exactly).  ``timings``
-        receives a device dispatch's phase seconds and bytes sent."""
+        the requested device backend over the epoch's resident planes;
+        everything else is the bit-exact float64 walk (= the old per-layer
+        path exactly).  ``timings`` receives a device dispatch's phase
+        seconds and bytes sent."""
         from repro.kernels import fused_descent as fd
         if st.packed is not None:
             return fd.fused_descent_with_backend(
                 st.prefix, q, backend=self.backend, packed=st.packed,
-                timings=timings)
+                resident=st.dev_planes, timings=timings)
         lo, hi = descend_layers(st.prefix, q)
         return lo, hi, "numpy", st.numpy_reason
 

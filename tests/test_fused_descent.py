@@ -142,6 +142,35 @@ def test_device_backends_step_exact_band_contained(stacks, name):
         assert np.max(np.abs(phi - jhi)) <= 4
 
 
+@pytest.mark.parametrize("backend", ["pallas", "jnp"])
+@pytest.mark.parametrize("name", sorted(MIXES))
+def test_resident_planes_bit_identical_to_per_call(stacks, name, backend):
+    """Planes uploaded once and passed as ``resident=`` give the very lo
+    and hi the per-call upload gives, for every depth and ragged batch;
+    only the query bytes are sent with a resident batch."""
+    prefixes, qs = stacks
+    for depth in (1, 2, 3):
+        layers = prefixes[name][:depth]
+        packed = fd.pack_prefix(layers)
+        resident, plane_bytes = fd.upload_planes(packed, backend)
+        assert plane_bytes == sum(
+            packed[k].nbytes for k in packed
+            if backend == "pallas" or k != "kinds")
+        for n in (1, 255, 600):
+            q = qs[:n]
+            per_call, at_rest = {}, {}
+            want = fd.fused_descent_with_backend(
+                layers, q, backend=backend, timings=per_call)
+            got = fd.fused_descent_with_backend(
+                layers, q, backend=backend, packed=packed,
+                resident=resident, timings=at_rest)
+            assert got[2:] == want[2:] == (backend, None)
+            np.testing.assert_array_equal(got[0], want[0])
+            np.testing.assert_array_equal(got[1], want[1])
+            assert per_call["h2d_bytes"] == at_rest["h2d_bytes"] \
+                + plane_bytes
+
+
 def test_ragged_batches_match_full_batch(stacks):
     prefixes, qs = stacks
     layers = prefixes["step-band-step"]
@@ -202,7 +231,8 @@ def test_fallback_chain_degrades_to_jnp_then_numpy(stacks):
 
 def test_kernel_failure_propagates_off_cpu(stacks, monkeypatch):
     """On an accelerator a failing kernel raises out of the dispatcher (no
-    silent jnp/numpy stand-in), and it is asked to run compiled."""
+    silent jnp/numpy stand-in), and its per-batch compiled entry is asked
+    to run compiled."""
     import jax
 
     import repro.kernels.fused_descent.kernel as kernel
@@ -217,7 +247,7 @@ def test_kernel_failure_propagates_off_cpu(stacks, monkeypatch):
         raise RuntimeError("kernel refused")
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    monkeypatch.setattr(kernel, "fused_descent_pallas", boom)
+    monkeypatch.setattr(kernel, "fused_descent_windows", boom)
     with pytest.raises(RuntimeError, match="kernel refused"):
         fd.fused_descent_with_backend(layers, qs, backend="pallas")
     assert calls == [{"interpret": False}]
@@ -238,14 +268,14 @@ def test_interpret_mode_follows_platform(stacks, monkeypatch, platform,
 
     prefixes, qs = stacks
     seen = []
-    real = kernel.fused_descent_pallas
+    real = kernel.fused_descent_windows     # the per-batch compiled entry
 
     def spy(*a, **k):
         seen.append(k["interpret"])
         return real(*a, interpret=True)     # this host can only interpret
 
     monkeypatch.setattr(jax, "default_backend", lambda: platform)
-    monkeypatch.setattr(kernel, "fused_descent_pallas", spy)
+    monkeypatch.setattr(kernel, "fused_descent_windows", spy)
     assert interpret_mode() is interpret
     fd.fused_descent_with_backend(prefixes["gstep3"], qs[:8],
                                   backend="pallas")
@@ -353,3 +383,55 @@ def test_engine_counts_batches_per_backend_and_reason(tmp_path):
     assert (b.pallas_batches, b.jnp_batches, b.numpy_batches) == (0, 0, 2)
     assert b.numpy_key_range_batches == 2
     assert b.numpy_query_range_batches == b.numpy_width_batches == 0
+
+
+def test_engine_uploads_planes_once_per_epoch(tmp_path):
+    """A device-backed service puts its resident planes on the device once
+    per epoch, however many batches it serves, through ``lookup`` and the
+    pipelined ``lookup_batches`` (whose prefetch worker descends too);
+    ``swap`` uploads the new epoch's.  Answers stay within the device's
+    slack of the numpy backend's."""
+    from repro.serve.index_service import ServeStats
+
+    rng = np.random.default_rng(23)
+    keys = np.unique(rng.integers(1, 2**30, 50_000).astype(np.uint64))
+    D = KeyPositions.fixed_record(keys, 16)
+    paths = []
+    for name in ("band-eband-step", "step-band-step"):
+        paths.append(str(tmp_path / f"{name}.air"))
+        write_index(paths[-1], _design(D, MIXES[name]), page_bytes=1024)
+    batches = [rng.choice(D.keys, n) for n in (300, 1, 257, 64, 512, 90)]
+    spec = ServeSpec(resident_layers=2, cache_bytes=(8 << 10,),
+                     pipeline_depth=2)
+
+    def check(got, want, q):
+        assert np.all(got[:, 0] <= want[:, 0])
+        assert np.all(got[:, 1] >= want[:, 1])
+        idx = np.searchsorted(D.keys, q)
+        assert np.all((got[:, 0] <= D.lo[idx]) & (got[:, 1] >= D.hi[idx]))
+
+    wants = {}
+    for path in paths:
+        with IndexService(path, profile=None, spec=spec) as ref_svc:
+            wants[path] = ref_svc.lookup_batches(batches)
+    with IndexService(path := paths[0], profile=None,
+                      spec=spec.replace(backend="pallas")) as svc:
+        assert svc.stats.plane_uploads == 1
+        for q, want in zip(batches, wants[path]):
+            check(svc.lookup(q), want, q)
+        for q, got, want in zip(batches, svc.lookup_batches(batches),
+                                wants[path]):
+            check(got, want, q)
+        s = svc.stats
+        assert s.plane_uploads == 1 and s.pipelined_batches == len(batches)
+        assert s.pallas_batches == 2 * len(batches)
+        assert ServeStats.from_snapshot(s.snapshot()).plane_uploads == 1
+        old = svc._st
+        svc.swap(path := paths[1])
+        assert old.dev_planes is None           # released: nothing pinned
+        assert svc.stats.plane_uploads == 2 and svc.stats.swaps == 1
+        for q, got, want in zip(batches, svc.lookup_batches(batches),
+                                wants[path]):
+            check(got, want, q)
+        assert svc.stats.plane_uploads == 2
+        assert svc.stats.pallas_batches == len(batches)
