@@ -88,16 +88,14 @@ def _serve_phase(name, index, keys, data_size, queries, resident):
         if planes is None:
             raise AssertionError(f"phase {name}: the resident prefix does "
                                  f"not pack for the device")
-        L, _, P = planes["keys"].shape
+        L, _, P = planes["key_hi"].shape
         times = [_check_batch(svc, keys, data_size, q) for q in queries]
         s = svc.stats
         _say(f"phase {name}: {L} resident layer(s), padded width P={P}, "
              f"{len(queries)} batches x {len(queries[0])} keys")
         _say(f"phase {name}: batches by backend: pallas={s.pallas_batches} "
              f"(interpret={s.interpret_batches}) jnp={s.jnp_batches} "
-             f"numpy={s.numpy_batches} (width={s.numpy_width_batches} "
-             f"key_range={s.numpy_key_range_batches} "
-             f"query_range={s.numpy_query_range_batches})")
+             f"numpy={s.numpy_batches} (width={s.numpy_width_batches})")
         _say(f"phase {name}: every range holds its searchsorted record")
         _say(f"phase {name} smoke timing, not a benchmark: first batch "
              f"(includes compile) {times[0]:.3f} s, later batches "

@@ -1,7 +1,7 @@
-from .ops import (MAX_VMEM_ENTRIES, band_f32_slack, fused_descent,
-                  fused_descent_with_backend, pack_prefix, prefix_gate,
-                  upload_planes)
+from .ops import (MAX_VMEM_ENTRIES, PLANES, SIGN, band_f32_slack,
+                  fused_descent, fused_descent_with_backend, pack_prefix,
+                  prefix_gate, split_words, upload_planes)
 
-__all__ = ["MAX_VMEM_ENTRIES", "band_f32_slack", "fused_descent",
-           "fused_descent_with_backend", "pack_prefix", "prefix_gate",
-           "upload_planes"]
+__all__ = ["MAX_VMEM_ENTRIES", "PLANES", "SIGN", "band_f32_slack",
+           "fused_descent", "fused_descent_with_backend", "pack_prefix",
+           "prefix_gate", "split_words", "upload_planes"]

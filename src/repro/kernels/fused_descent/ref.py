@@ -6,12 +6,12 @@ Two oracles with different contracts:
     :func:`repro.core.descent.descend_layers`, i.e. literally the per-layer
     path the serving engine used before fusion; every numpy-backend result
     of ``ops.fused_descent`` must be bit-identical to it.
-  * :func:`fused_descent_jnp` — pure-jnp f32 oracle over the *packed*
-    planes, mirroring the kernel's semantics (int32 keys, f32 band math on
-    the slack-widened δ, per-layer ``hi ≥ lo+1`` on band rows).  This is
-    both the kernel's test oracle and the ``backend="jnp"`` path; it may
-    differ from the kernel by a few ULP of the f32 band midpoint (FMA
-    contraction), never more.
+  * :func:`fused_descent_jnp` — pure-jnp oracle over the *packed* planes,
+    mirroring the kernel's semantics (the two-word rank, the band window
+    relative to its node on the exact key difference, the same (3, L, Q)
+    output).  This is both the kernel's test oracle and the
+    ``backend="jnp"`` path; it may differ from the kernel by a few ULP of
+    the float32 band midpoint (FMA contraction), never more.
 """
 from __future__ import annotations
 
@@ -26,34 +26,33 @@ def fused_descent_ref(layers, queries: np.ndarray):
 
 
 def fused_descent_jnp(planes: dict, queries):
-    """jnp f32 oracle over packed planes → (lo, hi) int32 of shape (L, Q).
+    """jnp oracle over packed planes → (3, L, Q) int32, the kernel's
+    ``fused_descent_windows`` output: covering entry in the flattened
+    (L·P) planes, then the band ends relative to the node as float32 bits
+    (zeros on step rows).
 
     ``planes`` is the dict built by ``ops.pack_prefix`` (numpy or jnp
-    arrays); ``queries`` int32, in-range per the packer's guards.
+    arrays); ``queries`` the (2, Q) int32 words of ``ops.split_words``.
     """
     import jax.numpy as jnp
 
+    from .kernel import band_window, rank_words, stack_windows
+
     q = jnp.asarray(queries, jnp.int32)
-    qf = q.astype(jnp.float32)
     kinds = np.asarray(planes["kinds"])
-    rows = {k: jnp.asarray(planes[k])[:, 0] for k in
-            ("keys", "pos_lo", "pos_hi", "x1", "y1", "m", "delta")}
-    keys = rows["keys"]
-    los, his = [], []
-    for l in range(keys.shape[0]):
-        # rank − 1 == searchsorted-right − 1: the covering partition
-        i = jnp.clip(jnp.searchsorted(keys[l], q, side="right") - 1, 0, None)
+    rows = {k: jnp.asarray(planes[k])[:, 0]
+            for k in ("key_hi", "key_lo", "m", "delta")}
+    P = rows["key_hi"].shape[1]
+    idx, los, his = [], [], []
+    for l in range(len(kinds)):
+        kh, kl = rows["key_hi"][l], rows["key_lo"][l]
+        i = jnp.maximum(rank_words(kh, kl, q[0], q[1]) - 1, 0)
+        idx.append(i + l * P)
         if kinds[l] == 1:
-            x1 = rows["x1"][l][i]
-            y1 = rows["y1"][l][i]
-            m = rows["m"][l][i]
-            d = rows["delta"][l][i]
-            mid = y1 + m * (qf - x1)
-            lo = jnp.floor(mid - d).astype(jnp.int32)
-            hi = jnp.maximum(jnp.ceil(mid + d).astype(jnp.int32), lo + 1)
+            lo, hi = band_window(q[0], q[1], kh[i], kl[i], rows["m"][l][i],
+                                 rows["delta"][l][i])
         else:
-            lo = rows["pos_lo"][l][i]
-            hi = rows["pos_hi"][l][i]
+            lo = hi = jnp.zeros(q.shape[1], jnp.float32)
         los.append(lo)
         his.append(hi)
-    return jnp.stack(los), jnp.stack(his)
+    return stack_windows(jnp.stack(idx), jnp.stack(los), jnp.stack(his))

@@ -19,8 +19,9 @@ serves *batches* against one serialized index through three mechanisms:
      Alg. 1) and descended in ONE fused dispatch per batch
      (:mod:`repro.kernels.fused_descent`): the numpy backend is the
      bit-exact float64 walk; ``backend="pallas"``/``"jnp"`` run the fused
-     f32 kernel, and only batches its int32 planes cannot represent go to
-     numpy — counted per reason in :class:`ServeStats`;
+     kernel over the 64-bit keys as 32-bit words, and only a prefix wider
+     than the kernel's planes goes to numpy — counted in
+     :class:`ServeStats`;
   4. **two-stage pipeline** — :meth:`IndexService.lookup_batches` with
      ``spec.pipeline_depth > 0`` overlaps the fused descent + disk walk of
      batch *i* (stage 2, this thread) with the coalesced first-window
@@ -38,9 +39,10 @@ bit-for-bit.  Observed hit rates feed back into tuning via
 
 Every layer boundary of a served lookup is a :func:`repro.spans.span`
 (``airindex.lookup`` → ``airindex.descent`` with its ``stage`` /
-``launch`` / ``collect`` phases, and ``airindex.walk`` →
-``airindex.walk.fetch``): on a profiler trace beside the device's
-operations, and summed into :class:`ServeStats` under the lock.
+``launch`` / ``collect`` phases, ``collect`` holding ``rebase``, and
+``airindex.walk`` → ``airindex.walk.fetch``): on a profiler trace beside
+the device's operations, and summed into :class:`ServeStats` under the
+lock.
 """
 from __future__ import annotations
 
@@ -193,10 +195,8 @@ class ServeStats:
     jnp_batches: int = 0
     numpy_batches: int = 0
     # ... and the batches a device backend sent to numpy, by reason
-    # (repro.kernels.fused_descent.prefix_gate / the query-range check)
+    # (repro.kernels.fused_descent.prefix_gate)
     numpy_width_batches: int = 0
-    numpy_key_range_batches: int = 0
-    numpy_query_range_batches: int = 0
     pipelined_batches: int = 0  # batches served through lookup_batches'
     #                             two-stage pipeline
     overlapped_preads: int = 0  # preads issued by the prefetch stage while
@@ -213,12 +213,16 @@ class ServeStats:
     # plus coalesced preads)
     lookup_seconds: float = 0.0
     descent_seconds: float = 0.0
-    descent_stage_seconds: float = 0.0    # query cast and pad
+    descent_stage_seconds: float = 0.0    # query words and pad
     descent_launch_seconds: float = 0.0   # the one compiled call
-    descent_collect_seconds: float = 0.0  # wait, copy-back, float64
+    descent_collect_seconds: float = 0.0  # wait, copy-back, rebase
+    rebase_seconds: float = 0.0  # collect's widening of the windows to
+    #                              byte offsets (int64 bases added)
     h2d_bytes: int = 0          # host arrays handed to the device: the
     #                             resident planes once per epoch, then
-    #                             each batch's queries
+    #                             each batch's queries, 8 B a query
+    wide_queries: int = 0       # queries handed to the device whose key
+    #                             has a nonzero high 32-bit word
     plane_uploads: int = 0      # resident-prefix uploads: one per epoch
     #                             (carried forward, like ``swaps``)
     walk_seconds: float = 0.0
@@ -392,7 +396,8 @@ class ServeStats:
 
 def _count_backend(stats: ServeStats, used: str, reason) -> None:
     """Attribute one resident-descent batch to the backend that served it
-    (and, for numpy standing in for a device backend, to the reason)."""
+    (and, for numpy standing in for a device backend, to the reason: only
+    ``"width"`` is left)."""
     if used == "pallas":
         from repro.kernels import interpret_mode
         stats.pallas_batches += 1
@@ -401,9 +406,7 @@ def _count_backend(stats: ServeStats, used: str, reason) -> None:
         stats.jnp_batches += 1
     else:
         stats.numpy_batches += 1
-        if reason is not None:
-            name = f"numpy_{reason}_batches"
-            setattr(stats, name, getattr(stats, name) + 1)
+        stats.numpy_width_batches += reason == "width"
 
 
 # ---------------------------------------------------------------------------
@@ -798,7 +801,7 @@ class IndexService:
             st.prefix = [st.resident[li] for li in st.prefix_lis]
             # a device backend packs the prefix and puts its planes on
             # the device once per epoch, so a batch sends only its
-            # queries; a prefix the int32 planes cannot hold serves on
+            # queries; a prefix wider than the kernel's planes serves on
             # numpy, and every batch is counted under the reason
             st.numpy_reason = None
             if spec.backend != "numpy" and st.prefix:
@@ -1394,7 +1397,9 @@ class IndexService:
                     "launch_seconds", 0.0)
                 st.stats.descent_collect_seconds += phases.get(
                     "collect_seconds", 0.0)
+                st.stats.rebase_seconds += phases.get("rebase_seconds", 0.0)
                 st.stats.h2d_bytes += phases.get("h2d_bytes", 0)
+                st.stats.wide_queries += phases.get("wide_queries", 0)
                 st.stats.walk_modeled_seconds += walk
                 _count_backend(st.stats, used, reason)
             lo, hi = plo[-1], phi[-1]

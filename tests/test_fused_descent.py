@@ -1,8 +1,9 @@
 """Fused multi-layer descent: bit-identity of the numpy backend with the
-per-layer walk, device-backend step-exactness / band containment, ragged
-batches, the visible numpy fallback (per reason) and failures that
-propagate, the platform-chosen interpret mode, and packing guards —
-across layer-family mixes (gstep/gband/eband/rmi_leaf) and prefix depths."""
+per-layer walk, device-backend step-exactness / band containment within
+the node-local slack (also over 64-bit keys and byte offsets past 2^33),
+ragged batches, the visible numpy fallback and failures that propagate,
+the platform-chosen interpret mode, and packing guards — across
+layer-family mixes (gstep/gband/eband/rmi_leaf) and prefix depths."""
 import numpy as np
 import pytest
 
@@ -10,7 +11,8 @@ from repro.api import ServeSpec
 from repro.core import IndexDesign, KeyPositions, write_index
 from repro.core.baselines import build_rmi_leaf
 from repro.core.builders import build_eband, build_gband, build_gstep
-from repro.core.descent import descend_band_layer, descend_step_layer
+from repro.core.descent import (covering_index, descend_band_layer,
+                                descend_step_layer)
 from repro.kernels import fused_descent as fd
 from repro.core.nodes import outline
 from repro.serve.index_service import IndexService
@@ -47,9 +49,8 @@ def _design(D, kinds):
 @pytest.fixture(scope="module")
 def stacks(tmp_path_factory):
     """{mix name: top-down parsed resident prefix (all 3 layers)} plus
-    in-domain queries — parsed through the real IndexService path.
-    Keys stay below 2**30 so the device backends are eligible (int32
-    packing guard, same bound as the previous use_device gating)."""
+    in-domain queries — parsed through the real IndexService path, over
+    keys below 2**30 (the 64-bit cases are ``wide_stacks``)."""
     rng0 = np.random.default_rng(11)
     keys = np.unique(rng0.integers(1, 2**30, 60_000).astype(np.uint64))
     D = KeyPositions.fixed_record(keys, 16)
@@ -79,6 +80,31 @@ def _per_layer_walk(prefix, q):
                                         lay["m"], lay["delta"], q)
         lo[r], hi[r] = l_, h_
     return lo, hi
+
+
+def _slack(lay, q):
+    """Per query, the most a device band row may exceed the float64
+    window at each end: the node-local slack at the query's own distance
+    from its node, plus the float32 error it covers (itself under the
+    slack), plus the ends' rounding to whole bytes."""
+    j = covering_index(lay["x1"], q)
+    span = lay["m"][j] * (q - lay["x1"][j]).astype(np.float64)
+    return 2.0 * fd.band_f32_slack(span, lay["delta"][j]) + 1.0
+
+
+def _check_device_rows(layers, packed, q, want, got):
+    """Step rows bit-exact; band rows hold the float64 window and exceed
+    it by no more than the slack."""
+    (rlo, rhi), (lo, hi) = want, got
+    for r, lay in enumerate(layers):
+        if packed["kinds"][r] == 0:
+            np.testing.assert_array_equal(lo[r], rlo[r])
+            np.testing.assert_array_equal(hi[r], rhi[r])
+        else:
+            assert np.all(lo[r] <= rlo[r]) and np.all(hi[r] >= rhi[r])
+            bound = _slack(lay, q)
+            assert np.all(rlo[r] - lo[r] <= bound)
+            assert np.all(hi[r] - rhi[r] <= bound)
 
 
 # ---------------------------------------------------------------------------
@@ -125,18 +151,8 @@ def test_device_backends_step_exact_band_contained(stacks, name):
                                                         backend="jnp")
         assert pu == "pallas" and ju == "jnp"
         packed = fd.pack_prefix(layers)
-        for r, lay in enumerate(layers):
-            if packed["kinds"][r] == 0:          # step: exact on both
-                np.testing.assert_array_equal(plo[r], rlo[r])
-                np.testing.assert_array_equal(phi[r], rhi[r])
-                np.testing.assert_array_equal(jlo[r], rlo[r])
-                np.testing.assert_array_equal(jhi[r], rhi[r])
-            else:                                # band: contained + bounded
-                assert np.all(plo[r] <= rlo[r]) and np.all(phi[r] >= rhi[r])
-                assert np.all(jlo[r] <= rlo[r]) and np.all(jhi[r] >= rhi[r])
-                bound = 2.0 * float(np.max(fd.band_f32_slack(
-                    lay["y1"], lay["m"], lay["x1"]))) + 4.0
-                assert np.max((phi[r] - plo[r]) - (rhi[r] - rlo[r])) <= bound
+        for got in ((plo, phi), (jlo, jhi)):
+            _check_device_rows(layers, packed, qs, (rlo, rhi), got)
         # pallas vs jnp differ only by f32 FMA contraction on band mids
         assert np.max(np.abs(plo - jlo)) <= 4
         assert np.max(np.abs(phi - jhi)) <= 4
@@ -154,7 +170,7 @@ def test_resident_planes_bit_identical_to_per_call(stacks, name, backend):
         packed = fd.pack_prefix(layers)
         resident, plane_bytes = fd.upload_planes(packed, backend)
         assert plane_bytes == sum(
-            packed[k].nbytes for k in packed
+            packed[k].nbytes for k in fd.PLANES
             if backend == "pallas" or k != "kinds")
         for n in (1, 255, 600):
             q = qs[:n]
@@ -185,21 +201,24 @@ def test_ragged_batches_match_full_batch(stacks):
 
 
 # ---------------------------------------------------------------------------
-# fallback: only to numpy, only for batches the int32 planes cannot hold,
-# and always with a reason; a device backend's own failure propagates
+# fallback: only to numpy, only for a prefix wider than the planes, and
+# always with a reason; a device backend's own failure propagates
 # ---------------------------------------------------------------------------
-def _oversized_prefix():
-    """A one-layer step prefix whose keys reach 2**31 (key_range)."""
+def _high_prefix():
+    """A one-layer step prefix whose keys and positions pass 2**32."""
     return [{"kind": "step",
-             "keys": np.array([0, 2**31 + 5], dtype=np.uint64),
-             "pos_lo": np.array([0, 8], dtype=np.int64),
-             "pos_hi": np.array([8, 16], dtype=np.int64)}]
+             "keys": np.array([0, 2**31 + 5, 2**40, 2**64 - 2],
+                              dtype=np.uint64),
+             "pos_lo": np.array([0, 8, 2**33, 2**40], dtype=np.int64),
+             "pos_hi": np.array([8, 2**33, 2**40, 2**40 + 16],
+                                dtype=np.int64)}]
 
 
 def test_fallback_chain_degrades_to_jnp_then_numpy(stacks):
-    """No device chain is left: a packable batch is served by exactly the
-    requested backend, and numpy serves only unrepresentable batches,
-    naming the reason (width / key_range / query_range)."""
+    """No device chain is left: every batch a prefix of packable width
+    gives is served by exactly the requested backend — keys, positions
+    and queries past 2**31 included — and numpy serves only a prefix
+    wider than the planes, naming the reason (width)."""
     prefixes, qs = stacks
     layers = prefixes["gstep3"]
     want_lo, want_hi = fd.fused_descent(layers, qs, backend="numpy")
@@ -209,16 +228,21 @@ def test_fallback_chain_degrades_to_jnp_then_numpy(stacks):
         assert (used, reason) == (backend, None)
         np.testing.assert_array_equal(lo, want_lo)   # all-step: exact
 
-    big_q = np.array([3, 2**31 + 1], dtype=np.uint64)
+    big_q = np.array([3, 2**31 + 1, 2**64 - 1], dtype=np.uint64)
     lo, hi, used, reason = fd.fused_descent_with_backend(
         layers, big_q, backend="pallas")
-    assert (used, reason) == ("numpy", "query_range")
+    assert (used, reason) == ("pallas", None)
     np.testing.assert_array_equal(
         lo, fd.fused_descent(layers, big_q, backend="numpy")[0])
 
+    high_q = np.array([0, 2**31 + 4, 2**31 + 5, 2**40 + 1, 2**64 - 1],
+                      dtype=np.uint64)
+    want = fd.fused_descent(_high_prefix(), high_q, backend="numpy")
     lo, hi, used, reason = fd.fused_descent_with_backend(
-        _oversized_prefix(), qs, backend="pallas")
-    assert (used, reason) == ("numpy", "key_range")
+        _high_prefix(), high_q, backend="pallas")
+    assert (used, reason) == ("pallas", None)
+    np.testing.assert_array_equal(lo, want[0])
+    np.testing.assert_array_equal(hi, want[1])
 
     n = fd.MAX_VMEM_ENTRIES + 1
     wide = [{"kind": "step", "keys": np.arange(n, dtype=np.uint64),
@@ -287,12 +311,9 @@ def test_interpret_mode_follows_platform(stacks, monkeypatch, platform,
 # ---------------------------------------------------------------------------
 def test_pack_prefix_guards():
     assert fd.pack_prefix([]) is None
-    over = {"kind": "step",
-            "keys": np.array([0, 2**31 - 1], dtype=np.uint64),
-            "pos_lo": np.array([0, 8], dtype=np.int64),
-            "pos_hi": np.array([8, 16], dtype=np.int64)}
-    assert fd.pack_prefix([over]) is None
-    assert fd.prefix_gate([over]) == "key_range"
+    high = _high_prefix()
+    assert fd.prefix_gate(high) is None     # 64-bit keys and offsets pack
+    assert fd.pack_prefix(high)["bases"].dtype == np.int64
     n = fd.MAX_VMEM_ENTRIES + 1
     wide = {"kind": "step", "keys": np.arange(n, dtype=np.uint64),
             "pos_lo": np.arange(n, dtype=np.int64),
@@ -305,7 +326,12 @@ def test_pack_prefix_guards():
     assert fd.prefix_gate([ok]) is None
     planes = fd.pack_prefix([ok, ok])
     assert planes["kinds"].shape == (2,)
-    assert planes["keys"].shape == (2, 1, 128)      # one LANE-wide row
+    for k in fd.PLANES[1:]:
+        assert planes[k].shape == (2, 1, 128)       # one LANE-wide row
+    # padded with the layer's last entry, which ranks and predicts alike
+    assert np.all(planes["key_lo"][:, 0, 2:] == planes["key_lo"][:, :, 2])
+    hi_base = planes["bases"][1].reshape(2, 128)
+    assert np.all(hi_base[:, 2:] == 3)
 
 
 # ---------------------------------------------------------------------------
@@ -354,13 +380,15 @@ def test_engine_device_backend_valid_and_attributed(stacks, tmp_path):
 
 
 def test_engine_counts_batches_per_backend_and_reason(tmp_path):
-    """ServeStats attributes each batch to the backend that served it: a
-    packable prefix on ``pallas``; a prefix with keys ≥ 2**31 on numpy
-    under ``key_range``; an out-of-range query batch under
-    ``query_range``."""
+    """ServeStats attributes each batch to the backend that served it:
+    keys below 2**30 and keys past 2**31 alike on ``pallas``, a query past
+    2**32 too, with no numpy batch and no reason; ``wide_queries`` counts
+    the queries whose key has a nonzero high word, and ``rebase_seconds``
+    the widening of every batch's windows."""
     rng = np.random.default_rng(21)
     small = np.unique(rng.integers(1, 2**30, 20_000).astype(np.uint64))
     big = np.unique(rng.integers(2**31, 2**40, 20_000).astype(np.uint64))
+    big = big[big >= 2**32]
     got = {}
     for name, keys in (("small", small), ("big", big)):
         D = KeyPositions.fixed_record(keys, 16)
@@ -373,16 +401,16 @@ def test_engine_counts_batches_per_backend_and_reason(tmp_path):
             svc.lookup(rng.choice(D.keys, 300))
             svc.lookup(rng.choice(D.keys, 40))
             if name == "small":
-                svc.lookup(np.array([5, 2**31 + 7], dtype=np.uint64))
+                svc.lookup(np.array([5, 2**31 + 7, 2**33], dtype=np.uint64))
             got[name] = svc.stats
     s = got["small"]
-    assert (s.pallas_batches, s.jnp_batches, s.numpy_batches) == (2, 0, 1)
-    assert s.numpy_query_range_batches == 1
-    assert s.numpy_width_batches == s.numpy_key_range_batches == 0
+    assert (s.pallas_batches, s.jnp_batches, s.numpy_batches) == (3, 0, 0)
+    assert s.numpy_width_batches == 0 and s.wide_queries == 1
     b = got["big"]
-    assert (b.pallas_batches, b.jnp_batches, b.numpy_batches) == (0, 0, 2)
-    assert b.numpy_key_range_batches == 2
-    assert b.numpy_query_range_batches == b.numpy_width_batches == 0
+    assert (b.pallas_batches, b.jnp_batches, b.numpy_batches) == (2, 0, 0)
+    assert b.numpy_width_batches == 0 and b.wide_queries == 340
+    assert s.rebase_seconds > 0 and b.rebase_seconds > 0
+    assert b.rebase_seconds <= b.descent_collect_seconds
 
 
 def test_engine_uploads_planes_once_per_epoch(tmp_path):
@@ -435,3 +463,132 @@ def test_engine_uploads_planes_once_per_epoch(tmp_path):
             check(got, want, q)
         assert svc.stats.plane_uploads == 2
         assert svc.stats.pallas_batches == len(batches)
+
+
+# ---------------------------------------------------------------------------
+# 64-bit keys: the device path against the float64 walk, keys spanning
+# [1, 2**64) and data byte offsets past 2**33
+# ---------------------------------------------------------------------------
+# keys (and queries) at each word and sign boundary of the two-word form
+EDGE_KEYS = np.array([1, 2**31 - 1, 2**32, 2**63 - 1, 2**63, 2**64 - 1],
+                     dtype=np.uint64)
+DATA_BASE = 2**33       # the data's first byte: every offset passes 2**33
+
+
+def _dense_run(rng, start: int, n: int) -> np.ndarray:
+    """``n`` keys from ``start`` about 10^9 apart: closer than float32's
+    ULP there (2^39 at 2^63), so rounding a key itself to float32 moves
+    its prediction by hundreds of records."""
+    gaps = rng.integers(5 * 10**8, 15 * 10**8, n, dtype=np.uint64)
+    return np.uint64(start) + np.cumsum(gaps, dtype=np.uint64)
+
+
+@pytest.fixture(scope="module")
+def wide_stacks(tmp_path_factory):
+    """{mix name: top-down parsed resident prefix} over 40k keys drawn
+    from all of [1, 2**64), ``EDGE_KEYS``, and two dense runs, one across
+    2**63 and one ending just below 2**64 - 1; records laid out from
+    ``DATA_BASE``; queries are the edge keys and a sample of the rest."""
+    rng = np.random.default_rng(64)
+    keys = np.concatenate([
+        rng.integers(1, 2**64 - 1, 40_000, dtype=np.uint64, endpoint=True),
+        EDGE_KEYS, _dense_run(rng, 2**63 - 5 * 10**12, 10_000),
+        _dense_run(rng, 2**64 - 2 * 10**13, 10_000)])
+    keys = np.unique(keys)
+    D = KeyPositions.fixed_record(keys, 16, base=DATA_BASE)
+    qs = np.concatenate([EDGE_KEYS, rng.choice(D.keys, 700)])
+    root = tmp_path_factory.mktemp("fused64")
+    out = {}
+    for name, kinds in MIXES.items():
+        path = str(root / f"{name}.air")
+        write_index(path, _design(D, kinds), page_bytes=1024)
+        with IndexService(path, profile=None,
+                          spec=ServeSpec(resident_layers=3)) as svc:
+            out[name] = svc._prefix
+    return out, qs
+
+
+@pytest.mark.parametrize("backend", ["pallas", "jnp"])
+@pytest.mark.parametrize("name", sorted(MIXES))
+def test_device_backends_on_64bit_keys(wide_stacks, name, backend):
+    prefixes, qs = wide_stacks
+    layers = prefixes[name]
+    want = fd.fused_descent(layers, qs, backend="numpy")
+    assert want[0].max() > DATA_BASE and qs.max() == 2**64 - 1
+    lo, hi, used, reason = fd.fused_descent_with_backend(layers, qs,
+                                                         backend=backend)
+    assert (used, reason) == (backend, None)
+    _check_device_rows(layers, fd.pack_prefix(layers), qs, want, (lo, hi))
+
+
+def test_band_slack_covers_wide_nodes():
+    """Keys spaced evenly through [2**63, 2**64) under 4 KiB records give
+    band nodes hundreds of MB wide, where float32 rounding of ``mid`` is
+    tens of bytes: the slack's share of the span covers it."""
+    rng = np.random.default_rng(9)
+    step = 2**63 // 100_000
+    keys = (np.uint64(2**63) + np.arange(100_000, dtype=np.uint64)
+            * np.uint64(step) + rng.integers(0, 2**20, 100_000,
+                                             dtype=np.uint64))
+    D = KeyPositions.fixed_record(keys, 4096, base=DATA_BASE)
+    layer = build_gband(D, 2**16)
+    assert layer.n_nodes < 16
+    path_layers = [{"kind": "band", "x1": layer.x1, "y1":
+                    layer.y1.astype(np.float64), "m": layer.m,
+                    "delta": layer.delta}]
+    q = rng.choice(keys, 2000)
+    want = fd.fused_descent(path_layers, q, backend="numpy")
+    packed = fd.pack_prefix(path_layers)
+    for backend in ("pallas", "jnp"):
+        got = fd.fused_descent(path_layers, q, backend=backend)
+        _check_device_rows(path_layers, packed, q, want, got)
+
+
+def test_split_words_order_as_uint64():
+    rng = np.random.default_rng(7)
+    a = np.concatenate([EDGE_KEYS, rng.integers(0, 2**64 - 1, 2000,
+                                                dtype=np.uint64)])
+    b = np.concatenate([EDGE_KEYS[::-1], a[len(EDGE_KEYS):][::-1]])
+    (ah, al), (bh, bl) = fd.split_words(a), fd.split_words(b)
+    assert ah.dtype == np.int32
+    le = (ah < bh) | ((ah == bh) & (al <= bl))
+    np.testing.assert_array_equal(le, a <= b)
+    # the words give the key back
+    back = ((ah.view(np.uint32) ^ fd.SIGN).astype(np.uint64) << np.uint64(32)) \
+        | (al.view(np.uint32) ^ fd.SIGN).astype(np.uint64)
+    np.testing.assert_array_equal(back, a)
+
+
+def test_band_slack_is_node_local():
+    """The slack grows with the node's own byte span, not with where the
+    node sits in the data: a node 8 MB wide gets about 10 bytes a side at
+    any offset, where a rule on |y1| gave 12.8 KB at 3.2 GB."""
+    assert fd.band_f32_slack(0.0, 0.0) == 2.0
+    s = fd.band_f32_slack(8 << 20, 8192)
+    assert 10.0 < s < 10.01
+    assert fd.band_f32_slack(-(8 << 20), -8192) == s
+
+
+def test_index_api_serves_64bit_keys_on_pallas(tmp_path):
+    """``Index.from_design → save → open → serve(backend="pallas")`` over
+    64-bit keys answers every lookup with a range that holds the key's
+    record, against ``np.searchsorted``; every batch on Pallas."""
+    from repro.api import Index
+
+    rng = np.random.default_rng(15)
+    keys = np.unique(np.concatenate([
+        rng.integers(1, 2**64 - 1, 30_000, dtype=np.uint64, endpoint=True),
+        EDGE_KEYS]))
+    D = KeyPositions.fixed_record(keys, 16)
+    path = str(tmp_path / "wide.air")
+    Index.from_design(_design(D, ("gband", "gstep", "gstep"))).save(path)
+    q = np.concatenate([EDGE_KEYS, rng.choice(keys, 500)])
+    with Index.open(path).serve(spec=ServeSpec(
+            backend="pallas", resident_layers=2)) as svc:
+        got = svc.lookup(q)
+        s = svc.stats
+    assert s.pallas_batches == s.batches == 1 and s.numpy_batches == 0
+    assert s.wide_queries == int(np.count_nonzero(q >= 2**32))
+    rec = np.searchsorted(keys, q).astype(np.int64) * 16
+    assert np.all((got[:, 0] <= rec) & (got[:, 1] >= rec + 16))
+    assert np.all((got[:, 0] >= 0) & (got[:, 1] <= 16 * len(keys)))

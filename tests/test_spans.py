@@ -64,6 +64,7 @@ def test_counters_nest_as_the_spans_do(served):
               s.descent_collect_seconds)
     assert all(p > 0 for p in phases)
     assert sum(phases) <= s.descent_seconds <= s.lookup_seconds
+    assert 0 < s.rebase_seconds <= s.descent_collect_seconds
     assert 0 < s.walk_fetch_seconds <= s.walk_seconds <= s.lookup_seconds
     assert s.descent_seconds + s.walk_seconds <= s.lookup_seconds
     assert s.walk_windows > 0
@@ -81,15 +82,18 @@ def test_h2d_bytes_match_a_count_by_hand(served):
         for b in batches:
             svc.lookup(b)
         sent = svc.stats.h2d_bytes
-    L, _, P = planes["keys"].shape
+    from repro.kernels.fused_descent import PLANES
+
+    L, _, P = planes["key_hi"].shape
     assert L == 2 and planes["kinds"].dtype == np.int32
-    # kinds (L,) int32 and seven (L, 1, P) planes of 4-byte words
-    plane_bytes = 4 * L + 7 * 4 * L * P
-    assert plane_bytes == sum(a.nbytes for a in planes.values())
+    # kinds (L,) int32 and four (L, 1, P) planes of 4-byte words; the
+    # int64 bases stay on the host
+    plane_bytes = 4 * L + 4 * 4 * L * P
+    assert plane_bytes == sum(planes[k].nbytes for k in PLANES)
     padded = sum(-(-n // BLOCK_Q) * BLOCK_Q for n in BATCHES)
     # the planes go up once, when the epoch opens; a batch sends its
-    # int32 queries, padded to the kernel's block
-    assert sent == plane_bytes + 4 * padded
+    # queries as two int32 words, padded to the kernel's block
+    assert sent == plane_bytes + 8 * padded
 
 
 def test_numpy_backend_counts_no_device_phase(served):
@@ -155,6 +159,8 @@ def test_profiler_trace_holds_the_spans_nested(served, tmp_path):
     phases = [spans[p][0] for p in PHASES]
     assert all(_inside(p, descent) for p in phases)
     assert all(a[1] <= b[0] for a, b in zip(phases, phases[1:]))
+    (rebase,) = spans["airindex.descent.rebase"]
+    assert _inside(rebase, spans["airindex.descent.collect"][0])
     fetches = spans["airindex.walk.fetch"]
     assert fetches and all(_inside(f, walk) for f in fetches)
 

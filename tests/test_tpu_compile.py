@@ -57,15 +57,21 @@ def _shape(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
+def _descent_args(L, P, Q, sharding):
+    """The kernel's arguments: (2, Q) query words, kinds (L,), the two
+    int32 key-word planes and the two float32 band planes."""
+    return ([_shape((2, Q), jnp.int32, sharding),
+             _shape((L,), jnp.int32, sharding)]
+            + [_shape((L, 1, P), jnp.int32, sharding)] * 2
+            + [_shape((L, 1, P), jnp.float32, sharding)] * 2)
+
+
 @pytest.mark.parametrize("L,P,Q", [(1, 128, 256), (3, 4096, 4096),
                                    (4, 1024, 65536)])
 def test_fused_descent_compiles_for_v5e(one_chip, no_persistent_cache,
                                         L, P, Q):
-    args = ([_shape((1, Q), jnp.int32, one_chip),
-             _shape((L,), jnp.int32, one_chip)]
-            + [_shape((L, 1, P), jnp.int32, one_chip)] * 3
-            + [_shape((L, 1, P), jnp.float32, one_chip)] * 4)
-    compiled = fused_descent_pallas.lower(*args, interpret=False).compile()
+    compiled = fused_descent_pallas.lower(
+        *_descent_args(L, P, Q, one_chip), interpret=False).compile()
     assert "tpu_custom_call" in compiled.as_text()
 
 
@@ -75,10 +81,7 @@ def test_fused_descent_custom_call_keeps_its_name(one_chip,
     compiled HLO: the ``pallas_call``'s own ``name=``, whatever the jitted
     wrapper around it is called."""
     L, P, Q = 2, 1536, 1024
-    args = ([_shape((1, Q), jnp.int32, one_chip),
-             _shape((L,), jnp.int32, one_chip)]
-            + [_shape((L, 1, P), jnp.int32, one_chip)] * 3
-            + [_shape((L, 1, P), jnp.float32, one_chip)] * 4)
+    args = _descent_args(L, P, Q, one_chip)
 
     @functools.partial(jax.jit, static_argnames=("interpret",))
     def renamed_wrapper(*a, interpret):
@@ -91,19 +94,17 @@ def test_fused_descent_custom_call_keeps_its_name(one_chip,
     assert re.match(rf"^%{KERNEL_NAME}\b", calls[0]), calls
 
 
-@pytest.mark.parametrize("L,P,Q", [(1, 256, 1024), (2, 1536, 2048)])
+@pytest.mark.parametrize("L,P,Q", [(1, 256, 1024), (2, 1536, 2048),
+                                   (1, 512, 4096)])
 def test_fused_descent_batch_entry_is_one_kernel(one_chip,
                                                  no_persistent_cache, L, P, Q):
-    """The serving engine's one compiled call a batch (query reshape,
-    kernel, lo and hi stacked) holds exactly one custom call, under the
-    kernel's name, so a profiler trace still shows one kernel event a
-    call."""
-    args = ([_shape((Q,), jnp.int32, one_chip),
-             _shape((L,), jnp.int32, one_chip)]
-            + [_shape((L, 1, P), jnp.int32, one_chip)] * 3
-            + [_shape((L, 1, P), jnp.float32, one_chip)] * 4)
-    lowered = fused_descent_windows.lower(*args, interpret=False)
-    assert lowered.out_info.shape == (2, L, Q)
+    """The serving engine's one compiled call a batch (the two-word
+    queries, kernel, covering entry and both ends stacked) holds exactly
+    one custom call, under the kernel's name, so a profiler trace still
+    shows one kernel event a call."""
+    lowered = fused_descent_windows.lower(*_descent_args(L, P, Q, one_chip),
+                                          interpret=False)
+    assert lowered.out_info.shape == (3, L, Q)
     text = lowered.compile().as_text()
     calls = [ln.split(" = ", 1)[0].split()[-1] for ln in text.splitlines()
              if 'custom_call_target="tpu_custom_call"' in ln]
