@@ -46,7 +46,17 @@ def descend_band_layer(node_keys: np.ndarray, x1: np.ndarray, y1: np.ndarray,
     the exact expression used at fit time; callers apply their own clamps
     (layer clamp bounds in memory, data extent at the end of a file walk).
     """
-    j = covering_index(node_keys, queries)
+    return band_prediction(x1, y1, m, delta,
+                           covering_index(node_keys, queries), queries)
+
+
+def band_prediction(x1: np.ndarray, y1: np.ndarray, m: np.ndarray,
+                    delta: np.ndarray, j: np.ndarray,
+                    queries: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
+    """Node ``j[k]``'s unclamped ``[⌊mid−δ⌋, ⌈mid+δ⌉)`` for query ``k`` —
+    the band evaluation of :func:`descend_band_layer` once the covering
+    nodes are known (the on-disk walk finds them over a whole round's
+    records at once)."""
     dx = (queries - x1[j]).astype(np.float64)
     mid = y1[j].astype(np.float64) + np.asarray(m)[j] * dx
     d = np.asarray(delta)[j]

@@ -317,13 +317,14 @@ def record_keys(kind: str, raw: bytes) -> np.ndarray:
         "key" if kind == "step" else "x1"]
 
 
-def gallop_step(kind: str, a: int, b: int) -> int:
+def gallop_step(kind: str, a, b):
     """Extension step for a missed window ``[a, b)`` — the window's own
     width, but never less than one record of the layer's dtype: a
     zero-width window (``b == a`` after clamping) would otherwise retry
-    with the same bounds forever.  Shared by :class:`SerializedIndex` and
-    the serving engine so their gallop walks stay in lockstep."""
-    return max(b - a, RECORD_BYTES[kind])
+    with the same bounds forever.  Elementwise over arrays of windows.
+    Shared by :class:`SerializedIndex` and the serving engine so their
+    gallop walks stay in lockstep."""
+    return np.maximum(np.subtract(b, a), RECORD_BYTES[kind])
 
 
 def window_misses(kind: str, raw: bytes, a: int, b: int, layer_size: int,
@@ -395,7 +396,8 @@ class SerializedIndex:
                 left, right = window_misses(lm.kind, raw, a, b, lm.size, q1)
                 if not (left[0] or right[0]):
                     break
-                w = gallop_step(lm.kind, a, b)  # toward the covering record
+                # toward the covering record
+                w = int(gallop_step(lm.kind, a, b))
                 if left[0]:
                     a = max(a - w, 0)
                 else:

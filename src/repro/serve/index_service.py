@@ -56,11 +56,10 @@ from collections import OrderedDict
 
 import numpy as np
 
-from repro.core.descent import coalesce_ranges, descend_layers
-from repro.core.serialize import (_BAND_DT, _STEP_DT, gallop_step, page_crc,
-                                  page_span, parse_meta,
-                                  predict_from_records, record_aligned_range,
-                                  window_misses)
+from repro.core.descent import band_prediction, coalesce_ranges, descend_layers
+from repro.core.serialize import (_BAND_DT, _STEP_DT, RECORD_BYTES,
+                                  gallop_step, page_crc, page_span,
+                                  parse_meta, record_aligned_range)
 from repro.core.storage import (CachedProfile, DistributionalProfile,
                                 MeasuredProfile, PROFILES, StorageProfile)
 from repro.serve.backend import (CorruptPageError, DeadlineExceededError,
@@ -228,6 +227,9 @@ class ServeStats:
     walk_seconds: float = 0.0
     walk_fetch_seconds: float = 0.0
     walk_windows: int = 0       # distinct windows the disk walk scanned
+    walk_rounds: int = 0        # rounds of the disk walk: one per on-disk
+    #                             layer per batch, plus each further round
+    #                             that galloping misses force
     prefetch_seconds: float = 0.0  # the prefetch stage's own wall
     overlapped_pread_seconds: float = 0.0  # measured wall of tagged preads
     # what the *uncached* Alg. 1 walk (lookup_serialized) would pay for the
@@ -590,6 +592,87 @@ def observed_profile_from_stats(stats: ServeStats, backing: StorageProfile,
     # default name kept so a measured=False observed profile compares equal
     # to IndexService.cached_profile() (frozen-dataclass field equality)
     return CachedProfile(backing=eff, cache=cache, hit_rate=stats.hit_rate)
+
+
+# ---------------------------------------------------------------------------
+# the on-disk walk's round search
+# ---------------------------------------------------------------------------
+def distinct_windows(a: np.ndarray, b: np.ndarray):
+    """``np.unique`` of the rows ``(a[k], b[k])`` with ``return_inverse``
+    — the distinct windows sorted by start, then end, and each query's
+    window — by one ``lexsort`` (``np.unique`` sorts the rows through a
+    structured view, about ten times slower)."""
+    order = np.lexsort((b, a))
+    sa, sb = a[order], b[order]
+    new = np.ones(len(order), dtype=bool)
+    new[1:] = (sa[1:] != sa[:-1]) | (sb[1:] != sb[:-1])
+    inv = np.empty(len(order), dtype=np.intp)
+    inv[order] = np.cumsum(new) - 1
+    return np.stack([sa[new], sb[new]], axis=1), inv
+
+
+def _window_runs(lm, ab, page_bytes: int):
+    """The windows ``ab`` of layer ``lm`` coalesced into disjoint runs of
+    file bytes, each cut at page boundaries → ``(rs, re, pid, lo, hi)``:
+    the runs, then bytes ``[lo, hi)`` of page ``pid`` for each piece, run
+    after run in file order (a page two runs share appears twice)."""
+    rs, re_ = coalesce_ranges(lm.offset + ab[:, 0], lm.offset + ab[:, 1])
+    pa, pb = page_span(rs, re_ - rs, page_bytes)
+    n = pb - pa
+    pid = np.repeat(pa - (np.cumsum(n) - n), n) + np.arange(int(n.sum()))
+    base = pid * page_bytes
+    lo = np.maximum(np.repeat(rs, n) - base, 0)
+    hi = np.minimum(np.repeat(re_, n) - base, page_bytes)
+    return rs, re_, pid, lo, hi
+
+
+def _round_search(lm, ab, inv, q, runs, pages):
+    """One round of the on-disk walk over every window at once.
+
+    ``ab`` holds the round's distinct layer-relative windows
+    (:func:`distinct_windows`), ``inv`` each query's window, ``runs``
+    their :func:`_window_runs`, ``pages`` the bytes of every page those
+    span.  The windows are record-aligned ranges of one sorted record
+    array, so the records of their runs, concatenated, are a sorted
+    subsequence of the layer: one ``searchsorted`` over it finds every
+    query's covering record.  Returns ``(left, right, ok, lo, hi)``:
+    :func:`repro.core.serialize.window_misses`' flags per query, the
+    queries that miss neither way, and the layer's prediction for them —
+    bit-identical to :func:`repro.core.serialize.predict_from_records`
+    on each window."""
+    rs, re_, pid, plo, phi = runs
+    rsz = RECORD_BYTES[lm.kind]
+    raw = b"".join([pages[p][x:y] for p, x, y in
+                    zip(pid.tolist(), plo.tolist(), phi.tolist())])
+    step = lm.kind == "step"
+    rec = np.frombuffer(raw, dtype=_STEP_DT if step else _BAND_DT)
+    keys = rec["key" if step else "x1"]
+    # each window's first and last record in the concatenation
+    fa, fb = lm.offset + ab[:, 0], lm.offset + ab[:, 1]
+    n_rec = (re_ - rs) // rsz
+    r = np.searchsorted(rs, fa, side="right") - 1
+    first = (np.cumsum(n_rec) - n_rec)[r] + (fa - rs[r]) // rsz
+    last = first + (fb - fa) // rsz - 1
+    wf, wl = first[inv], last[inv]
+    left = (keys[wf] > q) & (ab[inv, 0] > 0)
+    right = (keys[wl] <= q) & (ab[inv, 1] < lm.size)
+    ok = np.flatnonzero(~(left | right))
+    # a window that misses neither way holds the layer's rightmost key
+    # <= q (or starts the layer); the clip is covering_index's.  Sorted
+    # needles keep the search cache-friendly.
+    qo = q[ok]
+    order = np.argsort(qo, kind="stable")
+    i = np.empty(len(ok), dtype=np.intp)
+    i[order] = np.searchsorted(keys, qo[order], side="right") - 1
+    i = np.clip(i, wf[ok], wl[ok])
+    if step:
+        pos = rec["pos"]
+        nxt = pos[np.minimum(i + 1, len(pos) - 1)]
+        lo, hi = pos[i], np.where(i == wl[ok], np.int64(lm.end_pos), nxt)
+    else:
+        lo, hi = band_prediction(rec["x1"], rec["y1"], rec["m"],
+                                 rec["delta"], i, qo)
+    return left, right, ok, lo, hi
 
 
 # ---------------------------------------------------------------------------
@@ -1253,6 +1336,10 @@ class IndexService:
 
     def _descend_disk(self, st, lm, lo, hi, q: np.ndarray,
                       deadline: float | None = None):
+        """One on-disk layer for the whole batch, in rounds: each round
+        fetches the pages of its distinct windows once and searches them
+        all at once (:func:`_round_search`); queries whose window missed
+        gallop and go round again."""
         P = st.page_bytes
         a, b = record_aligned_range(lm.kind, lo, hi, lm.size)
         a, b = a.copy(), b.copy()       # per-query windows, grown on misses
@@ -1265,55 +1352,45 @@ class IndexService:
         out_hi = np.empty(len(q), dtype=np.float64)
         pending = np.arange(len(q))
         while len(pending):
-            ab, inv = np.unique(np.stack([a[pending], b[pending]], axis=1),
-                                axis=0, return_inverse=True)
-            inv = inv.reshape(-1)   # numpy 2.1 briefly returned (n, 1) here
-            fa, fb = lm.offset + ab[:, 0], lm.offset + ab[:, 1]
-            pa, pb = page_span(fa, fb - fa, P)      # elementwise over ranges
-            need: set = set()
-            for x, y in zip(pa.tolist(), pb.tolist()):
-                need.update(range(x, y))
+            ab, inv = distinct_windows(a[pending], b[pending])
+            runs = _window_runs(lm, ab, P)
             with span("airindex.walk.fetch") as fetch:
-                pages = self._ensure_pages(st, sorted(need), deadline)
+                pages = self._ensure_pages(st, np.unique(runs[2]).tolist(),
+                                           deadline)
+            left, right, ok, l_, h_ = _round_search(lm, ab, inv, q[pending],
+                                                    runs, pages)
+            out_lo[pending[ok]] = l_
+            out_hi[pending[ok]] = h_
+            # gallop the missed windows toward the covering record (same
+            # rule as SerializedIndex.lookup — parity preserved);
+            # gallop_step never returns 0, so a degenerate zero-width
+            # window still extends by ≥ one record instead of retrying
+            # the same bounds forever
+            w = gallop_step(lm.kind, ab[:, 0], ab[:, 1])[inv]
+            rmiss = right & ~left
+            a[pending[left]] = np.maximum(ab[inv[left], 0] - w[left], 0)
+            b[pending[rmiss]] = np.minimum(ab[inv[rmiss], 1] + w[rmiss],
+                                           lm.size)
+            # next round: each window's left misses, then its right ones
+            miss = np.flatnonzero(left | right)
+            miss = miss[np.argsort(2 * inv[miss] + rmiss[miss],
+                                   kind="stable")]
+            modeled = []
+            if self.profile is not None and len(miss):
+                # the scalar walk re-reads each extended window; summed
+                # per window, in window order, as it always was
+                cuts = np.flatnonzero(np.diff(inv[miss])) + 1
+                for ext in np.split(pending[miss], cuts):
+                    modeled.append(float(np.sum(self.profile(
+                        (b[ext] - a[ext]).astype(np.float64)))))
             with self._mu:
                 st.stats.walk_fetch_seconds += fetch.seconds
                 st.stats.walk_windows += len(ab)
-            still = []
-            for ui in range(len(ab)):
-                base = int(pa[ui]) * P
-                buf = b"".join(pages[p]
-                               for p in range(int(pa[ui]), int(pb[ui])))
-                raw = buf[int(fa[ui]) - base:int(fb[ui]) - base]
-                sub = pending[inv == ui]
-                left, right = window_misses(lm.kind, raw, int(ab[ui, 0]),
-                                            int(ab[ui, 1]), lm.size, q[sub])
-                ok = sub[~(left | right)]
-                if len(ok):
-                    l_, h_ = predict_from_records(lm.kind, raw, q[ok],
-                                                  lm.end_pos)
-                    out_lo[ok] = l_
-                    out_hi[ok] = h_
-                # gallop the missed windows toward the covering record
-                # (same rule as SerializedIndex.lookup — parity preserved);
-                # gallop_step never returns 0, so a degenerate zero-width
-                # window still extends by ≥ one record instead of retrying
-                # the same bounds forever
-                w = gallop_step(lm.kind, int(ab[ui, 0]), int(ab[ui, 1]))
-                lmiss, rmiss = sub[left], sub[right & ~left]
-                a[lmiss] = max(int(ab[ui, 0]) - w, 0)
-                b[rmiss] = min(int(ab[ui, 1]) + w, lm.size)
-                still.extend([lmiss, rmiss])
-                with self._mu:
-                    st.stats.retries += len(lmiss) + len(rmiss)
-                    if self.profile is not None \
-                            and (len(lmiss) or len(rmiss)):
-                        # the scalar walk re-reads each extended window
-                        ext = np.concatenate([lmiss, rmiss])
-                        st.stats.walk_modeled_seconds += float(np.sum(
-                            self.profile(
-                                (b[ext] - a[ext]).astype(np.float64))))
-            pending = (np.concatenate(still) if still
-                       else np.empty(0, dtype=np.int64))
+                st.stats.walk_rounds += 1
+                st.stats.retries += len(miss)
+                for t in modeled:
+                    st.stats.walk_modeled_seconds += t
+            pending = pending[miss]
         return out_lo, out_hi
 
     # -- public API ---------------------------------------------------------
@@ -1520,14 +1597,10 @@ class IndexService:
         for d in range(depth):
             lm = metas[n_disk - 1 - d]
             a, b = record_aligned_range(lm.kind, lo, hi, lm.size)
-            ab = np.unique(np.stack([a, b], axis=1), axis=0)
-            fa, fb = lm.offset + ab[:, 0], lm.offset + ab[:, 1]
-            pa, pb = page_span(fa, fb - fa, P)
-            need: set = set()
-            for x, y in zip(pa.tolist(), pb.tolist()):
-                need.update(range(x, y))
+            ab, _ = distinct_windows(a, b)
+            need = np.unique(_window_runs(lm, ab, P)[2])
             with self._mu:
-                missing = [pid for pid in sorted(need)
+                missing = [pid for pid in need.tolist()
                            if pid not in st.cache]
             if missing:
                 staged += len(self._fetch_missing(st, missing,
@@ -1542,41 +1615,31 @@ class IndexService:
 
     def _advance_windows(self, st: _ServeState, lm, a, b, q: np.ndarray):
         """Predict the next layer's windows from *cached* pages only
-        (``peek``: no promotion, no hit/miss skew).  Queries whose window
-        pages were evicted, or whose covering record lies outside the
-        first window, simply drop out of the prefetch — stage 2 serves
-        them at full fidelity."""
+        (``peek``: no promotion, no hit/miss skew), by the serving walk's
+        own round search.  Queries whose window pages were evicted, or
+        whose covering record lies outside the first window, simply drop
+        out of the prefetch — stage 2 serves them at full fidelity."""
         P = st.page_bytes
-        ab, inv = np.unique(np.stack([a, b], axis=1), axis=0,
-                            return_inverse=True)
-        inv = inv.reshape(-1)
-        fa, fb = lm.offset + ab[:, 0], lm.offset + ab[:, 1]
-        pa, pb = page_span(fa, fb - fa, P)
-        idx = np.arange(len(q))
-        los, his, qs = [], [], []
-        for ui in range(len(ab)):
-            with self._mu:
-                chunks = [st.cache.peek(p)
-                          for p in range(int(pa[ui]), int(pb[ui]))]
-            if any(c is None for c in chunks):
-                continue            # evicted under pressure: stop here
-            base = int(pa[ui]) * P
-            raw = b"".join(chunks)[int(fa[ui]) - base:int(fb[ui]) - base]
-            sub = idx[inv == ui]
-            left, right = window_misses(lm.kind, raw, int(ab[ui, 0]),
-                                        int(ab[ui, 1]), lm.size, q[sub])
-            ok = sub[~(left | right)]
-            if len(ok) == 0:
-                continue
-            l_, h_ = predict_from_records(lm.kind, raw, q[ok], lm.end_pos)
-            los.append(l_)
-            his.append(h_)
-            qs.append(q[ok])
-        if not qs:
+        ab, inv = distinct_windows(a, b)
+        ids = np.unique(_window_runs(lm, ab, P)[2])
+        with self._mu:
+            got = [st.cache.peek(p) for p in ids.tolist()]
+        # evicted under pressure: a window short of a page stops here
+        gone = np.concatenate([[0], np.cumsum([c is None for c in got])])
+        fa = lm.offset + ab[:, 0]
+        pa, pb = page_span(fa, lm.offset + ab[:, 1] - fa, P)
+        whole = (gone[np.searchsorted(ids, pb)]
+                 == gone[np.searchsorted(ids, pa)])
+        keep = whole[inv]
+        if not keep.any():
             e = np.empty(0, dtype=np.float64)
             return e, e, np.empty(0, dtype=np.uint64)
-        return (np.concatenate(los), np.concatenate(his),
-                np.concatenate(qs))
+        pages = {p: c for p, c in zip(ids.tolist(), got) if c is not None}
+        ab, q = ab[whole], q[keep]
+        _, _, ok, lo, hi = _round_search(
+            lm, ab, (np.cumsum(whole) - 1)[inv[keep]], q,
+            _window_runs(lm, ab, P), pages)
+        return lo, hi, q[ok]
 
     @property
     def tune_spec(self):

@@ -429,3 +429,96 @@ def test_measured_profile_excludes_overlapped_samples():
     for i in range(12):
         s2.record_read(4096 * (1 + i % 3), 1e-3, overlapped=True)
     assert measured_backing_profile(s2) is not None
+
+
+# ---------------------------------------------------------------------------
+# the on-disk walk searches every window of a round at once: answers and
+# counters must equal the per-window walk's exactly
+# ---------------------------------------------------------------------------
+@pytest.fixture(scope="module")
+def round_files(served, tmp_path_factory):
+    from repro.core.baselines import build_fixed_btree
+    D, design, path, qs = served
+    d = tmp_path_factory.mktemp("round")
+    keys = make_keys("uniform", 120_000, seed=5)
+    B = KeyPositions.fixed_record(keys, 16)
+    btree = str(d / "btree.air")
+    write_index(btree, build_fixed_btree(B, lam=512), page_bytes=512)
+    legacy = str(d / "legacy.air")
+    write_index(legacy, design)                    # page_bytes=0 layout
+    return {"btree": (btree, B.keys, 2), "band": (path, D.keys, 1),
+            "legacy": (legacy, D.keys, 1)}
+
+
+def _edge_queries(keys, rng):
+    k0, k1 = int(keys[0]), int(keys[-1])
+    return np.concatenate([
+        np.asarray([0, 1, k0 - 1, k0, k1, k1 + 1, 2**64 - 1], dtype=np.uint64),
+        rng.choice(keys, 200)])
+
+
+# case -> (file, cache tiers, batch); the counters are (walk_windows,
+# retries, pages_hit, walk_modeled_seconds) as the per-window walk
+# recorded them on this data
+_ROUND_CASES = {
+    "step_bottom": ("btree", (8 << 10,),
+                    lambda k, r: r.choice(k, 500)),
+    "band_gallop": ("band", (64 << 10, 512 << 10),
+                    lambda k, r: r.choice(k, 600)),
+    "unpaged_legacy": ("legacy", (1 << 20,),
+                       lambda k, r: r.choice(k, 400)),
+    "cache_below_round": ("band", (2048,),
+                          lambda k, r: r.choice(k, 400)),
+    "edge_keys_band": ("band", (256 << 10,), _edge_queries),
+    "edge_keys_step": ("btree", (8 << 10,), _edge_queries),
+    "repeated_keys": ("band", (256 << 10,),
+                      lambda k, r: r.permutation(
+                          np.repeat(r.choice(k, 60), 5))),
+}
+_ROUND_COUNTERS = {
+    "band_gallop": (720, 796, 518, 1.1189754057142864),
+    "cache_below_round": (595, 497, 48, 0.7373968800000009),
+    "edge_keys_band": (364, 240, 362, 0.37779776),
+    "edge_keys_step": (156, 0, 12, 0.31381197714285713),
+    "repeated_keys": (173, 260, 65, 0.5206009142857144),
+    "step_bottom": (225, 0, 16, 0.7567880228571429),
+    "unpaged_legacy": (595, 497, 147, 0.7373968800000009),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_ROUND_CASES))
+def test_round_walk_matches_serialized(round_files, case):
+    which, cache, make = _ROUND_CASES[case]
+    path, keys, resident = round_files[which]
+    qs = make(keys, np.random.default_rng(11))
+    batches = [qs, qs[::2]]            # the second meets a warm cache
+    with IndexService(path, profile="azure_ssd",
+                      spec=ServeSpec(cache_bytes=cache,
+                                     resident_layers=resident)) as svc:
+        got = [svc.lookup(b) for b in batches]
+        st = svc.stats
+        n_disk = len(svc.meta.layers) - resident
+    for g, b in zip(got, batches):
+        assert np.array_equal(g, lookup_serialized(path, None, b))
+    windows, retries, hits, modeled = _ROUND_COUNTERS[case]
+    assert (st.walk_windows, st.retries, st.pages_hit) \
+        == (windows, retries, hits)
+    assert st.walk_modeled_seconds == pytest.approx(modeled, rel=1e-12)
+    if retries:                        # galloping forced further rounds
+        assert st.walk_rounds >= 2 * len(batches)
+    else:                              # one round per on-disk layer
+        assert st.walk_rounds == n_disk * len(batches)
+
+
+@pytest.mark.parametrize("n", [0, 1, 7, 500])
+def test_distinct_windows_is_unique_rows(n):
+    from repro.serve.index_service import distinct_windows
+    rng = np.random.default_rng(n)
+    a = rng.integers(0, 6, n).astype(np.int64) * 16
+    b = a + rng.integers(1, 4, n).astype(np.int64) * 16
+    ab, inv = distinct_windows(a, b)
+    want, want_inv = np.unique(np.stack([a, b], axis=1).reshape(n, 2),
+                               axis=0, return_inverse=True)
+    assert np.array_equal(ab.reshape(-1, 2), want)
+    assert np.array_equal(inv, want_inv.reshape(-1))
+    assert np.array_equal(ab.reshape(-1, 2)[inv], np.stack([a, b], axis=1))
