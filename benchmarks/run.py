@@ -20,8 +20,7 @@ sys.path.insert(0, "src")
 
 from repro.compile_cache import enable_compile_cache
 from repro.core import (AffineProfile, KeyPositions, PROFILES, airtune,
-                        expected_latency, IndexDesign, make_builders,
-                        mean_read_volume)
+                        expected_latency, IndexDesign, mean_read_volume)
 from repro.core.baselines import (build_fixed_btree, data_calculator,
                                   homogeneous_airtune, tune_pgm, tune_rmi)
 from repro.data.datasets import DATASETS, sosd_like
@@ -212,32 +211,6 @@ def bench_sec22_heterogeneous():
 
 
 # ---------------------------------------------------------------------------
-# Batched lookup throughput (TPU-native path, jitted on CPU)
-# ---------------------------------------------------------------------------
-def bench_lookup_throughput():
-    import jax.numpy as jnp
-    from repro.kernels.index_lookup import ops as ilk
-    rng = np.random.default_rng(0)
-    keys = np.unique(rng.integers(0, 2**30, 500_000).astype(np.uint64))
-    D = KeyPositions.fixed_record(keys, RECORD)
-    res = airtune(D, PROFILES["hbm"],
-                  make_builders(lam_low=2**8, lam_high=2**16, base=2.0), k=3)
-    layers = ilk.device_arrays_from_design(res.design)
-    q = jnp.asarray(rng.choice(keys, 8192).astype(np.int32))
-    lo, hi = ilk.traverse_index(layers, q, use_ref=True)   # jit warmup
-    lo.block_until_ready()
-    t0 = time.perf_counter()
-    iters = 20
-    for _ in range(iters):
-        lo, hi = ilk.traverse_index(layers, q, use_ref=True)
-    lo.block_until_ready()
-    dt = (time.perf_counter() - t0) / iters
-    emit("lookup_batch8192", dt * 1e6,
-         f"{8192 / dt / 1e6:.1f}M lookups/s (jnp path, 1 CPU core); "
-         f"design={res.design.describe()}")
-
-
-# ---------------------------------------------------------------------------
 # Serving engine (batched lookups + tiered block cache) — BENCH_serve.json
 # ---------------------------------------------------------------------------
 SERVE_JSON_PATH = None     # set by main() via --serve-json
@@ -375,26 +348,6 @@ def bench_baseline():
         print(f"# wrote {BASELINE_JSON_PATH}", flush=True)
 
 
-# ---------------------------------------------------------------------------
-# Roofline table from the dry-run
-# ---------------------------------------------------------------------------
-def bench_roofline():
-    import os
-    path = "dryrun_results.jsonl"
-    if not os.path.exists(path):
-        emit("roofline", 0.0, "dryrun_results.jsonl missing — run dryrun")
-        return
-    from benchmarks import roofline
-    rows = roofline.table(path, "16x16")
-    for r in rows:
-        if r["status"] != "ok":
-            emit(f"roofline_{r['arch']}_{r['shape']}", 0.0, r["status"])
-            continue
-        emit(f"roofline_{r['arch']}_{r['shape']}", r["bound_s"] * 1e6,
-             f"dominant={r['dominant']} useful={r['useful_ratio']:.2f} "
-             f"mfu_bound={r['mfu_bound']:.3f}")
-
-
 BENCHES = [
     bench_fig2_example,
     bench_fig9_cold_lookup,
@@ -404,14 +357,12 @@ BENCHES = [
     bench_fig15_build_time,
     bench_fig20_topk,
     bench_sec22_heterogeneous,
-    bench_lookup_throughput,
     bench_serve,
     bench_fleet,
     bench_chaos,
     bench_p99,
     bench_tune,
     bench_baseline,
-    bench_roofline,
 ]
 
 

@@ -127,7 +127,7 @@ class KernelFallbackShapeRule(ProjectRule):
             if tree is None:
                 continue
             if not _has_backend_param(tree):
-                continue  # fixed-backend kernels (attention) are exempt
+                continue  # no backend= dispatch, no chain to check
             literals = _string_literals(tree)
             missing = [b for b in _BACKENDS if b not in literals]
             if missing:

@@ -3,9 +3,9 @@
 The paper traverses one key at a time (read → search → reconstruct node →
 predict).  The TPU-native adaptation (DESIGN.md §2) processes a *batch* of
 query keys per traversal step: each layer descent is a vectorized
-piece/node search plus a prediction, which is exactly what the Pallas
-kernel in ``repro.kernels.index_lookup`` implements on-device.  This module
-provides:
+piece/node search plus a prediction; on the device the whole resident
+prefix runs as one Pallas kernel (``repro.kernels.fused_descent``).  This
+module provides:
 
   * :func:`descend_step_layer` / :func:`descend_band_layer` — one layer of
     descent (re-exported from :mod:`repro.core.descent`); the single

@@ -18,7 +18,7 @@ notes that the optimization works with *any* monotonically increasing
     folds the ``E[T] + w·Q_p[T]`` objective into an additive ``C(Δ)`` so
     every mean-latency search ranks designs by the tail objective
     unchanged (see the class docstring for the bound),
-  * named profiles for the tiers a multi-pod TPU training stack talks to
+  * named profiles for the tiers an index on a TPU host can live on
     (object store / NFS / SSD / host DRAM / HBM / VMEM / ICI / DCN).
 
 Hardware adaptation (DESIGN.md §2): the paper profiles NFS/SSD/HDD; on a

@@ -1,2 +1,1 @@
 from .datasets import sosd_like, DATASETS
-from .store import ShardedTokenStore, write_token_store

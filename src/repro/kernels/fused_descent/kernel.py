@@ -1,7 +1,6 @@
 """Pallas TPU kernel: fused multi-layer index descent (whole Alg. 1 prefix).
 
-The per-layer ``index_lookup`` kernels pay one dispatch per resident layer;
-this kernel walks a batch of keys through the *entire* resident prefix in a
+This kernel walks a batch of keys through the *entire* resident prefix in a
 single ``pallas_call``.  The trick is the structural fact exploited by
 :func:`repro.core.descent.descend_layers`: every index layer covers the full
 key domain, so layer ``l``'s prediction is a function of the query key alone
@@ -10,9 +9,9 @@ fused grid instead of L chained dispatches.
 
 Grid ``(n_q_blocks, L)`` — the layer dimension is innermost, and TPU grids
 are executed sequentially per core, so the Pallas pipeline double-buffers
-the per-layer parameter planes (the ``flash_attention`` idiom: while layer
-``l`` computes, layer ``l+1``'s (1, P) plane blocks are already streaming
-into the second VMEM buffer).
+the per-layer parameter planes: while layer ``l`` computes, layer
+``l+1``'s (1, P) plane blocks are already streaming into the second VMEM
+buffer.
 
 Keys are 64-bit and the chip computes in 32-bit words, so every key and
 query travels as two int32 words (hi, lo), each with its sign bit flipped:

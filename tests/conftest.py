@@ -1,5 +1,5 @@
-"""Shared fixtures.  NOTE: no XLA_FLAGS here — smoke tests and benches must
-see the real single-device CPU; only launch/dryrun.py forces 512 devices."""
+"""Shared fixtures.  NOTE: no XLA_FLAGS here — tests must see the real
+single-device CPU."""
 import numpy as np
 import pytest
 
